@@ -22,7 +22,7 @@ import numpy as np
 
 from repro.core import CompressionConfig
 from repro.core.bfile import BasketFile, BasketWriter
-from repro.core.codec import HAVE_ZSTD, is_pure_python
+from repro.core.codec import is_pure_python
 from repro.io import CompressionEngine, PrefetchReader
 
 from .common import emit
@@ -74,9 +74,6 @@ def run(out_csv: str | None = None,
                     "comp_speedup": round(base_w / dt_w, 2),
                     "decomp_speedup": round(base_r / dt_r, 2),
                 })
-    if not HAVE_ZSTD:
-        print("# note: zstandard not installed; 'zstd' is the pure-Python "
-              "large-window fallback (process-pool scaling regime)")
     emit(rows, out_csv)
     return rows
 

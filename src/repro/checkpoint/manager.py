@@ -114,10 +114,7 @@ def _device_chunk_stream(x, rows_per: int, bf16: bool, stage_depth: int = 2):
                 exhausted = True
                 break
             sl = x[s:min(s + rows_per, n)]
-            try:
-                sl.copy_to_host_async()
-            except Exception:       # pragma: no cover - backend-dependent
-                pass
+            sl.copy_to_host_async()
             pending.append((s, sl))
         if pending:
             s, sl = pending.popleft()

@@ -30,6 +30,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
+import zstandard
 
 from .basket import (BasketMeta, ChecksumError, byte_offsets, join_baskets,
                      split_array, unpack_basket, unpack_basket_into)
@@ -53,10 +54,10 @@ _JOURNAL_MAGIC = "RBKJ1"
 # shape mismatches (ValueError, incl. ChecksumError), malformed metadata
 # (KeyError), torn preads (EOFError), a garbled *compressed* stream blowing
 # up inside a codec before the adler check runs (zlib.error / LZMAError /
-# IndexError from the pure-Python LZ4 match copier).  Staleness (OSError)
+# ZstdError / IndexError from the pure-Python LZ4 match copier).  Staleness (OSError)
 # is deliberately absent — a replaced file must never be "healed".
 _DECODE_ERRORS = (ValueError, KeyError, IndexError, EOFError,
-                  zlib.error, lzma.LZMAError)
+                  zlib.error, lzma.LZMAError, zstandard.ZstdError)
 
 
 class CorruptBasketError(ChecksumError):

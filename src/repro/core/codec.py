@@ -39,11 +39,7 @@ from . import lz4 as _lz4
 from . import precond as _precond
 from . import repro_deflate as _rdef
 
-try:
-    import zstandard as _zstd
-    HAVE_ZSTD = True
-except ImportError:  # pragma: no cover
-    HAVE_ZSTD = False
+import zstandard as _zstd
 
 __all__ = ["Codec", "CompressionConfig", "CODECS", "get_codec", "compress",
            "decompress", "decompress_into"]
@@ -176,17 +172,9 @@ CODECS: dict[str, Codec] = {
     "repro-deflate-ref": Codec("repro-deflate-ref", _rdef_ref_c, _rdef_d,
                                pure_python=True),
     "repro-zstd": Codec("repro-zstd", _rzstd_c, _rdef_d, pure_python=True),
+    "zstd": Codec("zstd", _zstd_c, _zstd_d),
+    "zstd-fast": Codec("zstd-fast", _zstd_fast_c, _zstd_d),
 }
-if HAVE_ZSTD:
-    CODECS["zstd"] = Codec("zstd", _zstd_c, _zstd_d)
-    CODECS["zstd-fast"] = Codec("zstd-fast", _zstd_fast_c, _zstd_d)
-else:
-    # offline fallback: the mechanism-faithful large-window engine stands in
-    # for libzstd (DESIGN.md §4); "zstd-fast" maps to low-level large-window.
-    CODECS["zstd"] = Codec("zstd", _rzstd_c, _rdef_d, pure_python=True)
-    CODECS["zstd-fast"] = Codec("zstd-fast",
-                                lambda d, l, dic: _rzstd_c(d, 1, dic), _rdef_d,
-                                pure_python=True)
 
 
 def is_pure_python(algo: str) -> bool:
@@ -211,7 +199,7 @@ class CompressionConfig:
     proposed extensions: a preconditioner pipeline and an optional trained
     dictionary."""
 
-    algo: str = "zstd" if HAVE_ZSTD else "zlib"
+    algo: str = "zstd"
     level: int = 5
     precond: str = "none"          # e.g. "bitshuffle4", "delta4+shuffle4"
     dictionary: Optional[bytes] = None

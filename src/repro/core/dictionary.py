@@ -5,9 +5,9 @@ that the *same* trained dictionary also helps ZLIB (via ``zdict``) and LZ4
 (via window priming) — "the generated dictionaries are useable for ZLIB and
 LZ4 as well" (§3).
 
-``train_dictionary`` uses libzstd's COVER trainer when the ``zstandard``
-package is present; offline (this container) it falls back to a pure-numpy
-frequent-segment trainer implementing the same idea COVER formalizes:
+``train_dictionary`` uses libzstd's COVER trainer; where that trainer
+rejects the corpus it falls back to a pure-numpy frequent-segment trainer
+implementing the same idea COVER formalizes:
 find byte segments that recur across samples and concatenate them,
 rarest-first, so the most frequent material sits at the *end* of the
 dictionary (closest to the compression window — both zlib's ``zdict`` and
@@ -25,13 +25,9 @@ from typing import Iterable, Optional
 
 import numpy as np
 
-try:
-    import zstandard as _zstd
-    HAVE_ZSTD = True
-except ImportError:  # pragma: no cover
-    HAVE_ZSTD = False
+import zstandard as _zstd
 
-__all__ = ["train_dictionary", "train_dictionary_numpy", "suggest_dict_size", "HAVE_ZSTD"]
+__all__ = ["train_dictionary", "train_dictionary_numpy", "suggest_dict_size"]
 
 
 def suggest_dict_size(samples: list[bytes], per_sample_frac: float = 0.05,
@@ -95,9 +91,7 @@ def train_dictionary(samples: Iterable[bytes], size: Optional[int] = None) -> by
     if len(samples) < 8:
         # too small a corpus for any trainer; raw-content prefix
         return b"".join(samples)[:size]
-    if HAVE_ZSTD:  # pragma: no cover - not available offline
-        try:
-            return _zstd.train_dictionary(size, samples).as_bytes()
-        except _zstd.ZstdError:
-            pass
-    return train_dictionary_numpy(samples, size)
+    try:
+        return _zstd.train_dictionary(size, samples).as_bytes()
+    except _zstd.ZstdError:
+        return train_dictionary_numpy(samples, size)

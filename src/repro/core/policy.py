@@ -27,19 +27,17 @@ from __future__ import annotations
 
 import numpy as np
 
-from .codec import CompressionConfig, HAVE_ZSTD
+from .codec import CompressionConfig
 
 __all__ = ["PROFILES", "choose", "precond_for_array"]
 
-_Z = "zstd" if HAVE_ZSTD else "zlib"
-
 PROFILES: dict[str, dict] = {
     # algo/level pairs per the paper's operating points
-    "production": {"algo": _Z, "level": 8},
+    "production": {"algo": "zstd", "level": 8},
     "analysis": {"algo": "lz4", "level": 1},
     "analysis-hc": {"algo": "lz4", "level": 6},
-    "checkpoint": {"algo": _Z, "level": 4},
-    "wire": {"algo": ("zstd-fast" if HAVE_ZSTD else "zlib"), "level": 3 if HAVE_ZSTD else 1},
+    "checkpoint": {"algo": "zstd", "level": 4},
+    "wire": {"algo": "zstd-fast", "level": 3},
     "archive": {"algo": "lzma", "level": 6},
     "off": {"algo": "none", "level": 0},
 }

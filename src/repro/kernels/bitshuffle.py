@@ -6,11 +6,14 @@ zero-copy checkpoint staging.  The host-side numpy twin lives in
 ``repro.core.precond``; semantics are defined by ``ref.bitshuffle_ref``.
 
 TPU mapping notes (DESIGN.md §3): bitshuffle is pure VPU work — shifts,
-masks and an 8-lane weighted reduction; no MXU involvement.  Tiles are
-chosen so a block of (block_n x itemsize) bytes plus its (8*itemsize x
-block_n/8) output fit comfortably in VMEM (default 64 KiB in + 64 KiB out
-per grid step), and the lane dimension (block_n) is a multiple of 1024 so
-both views keep 128-lane alignment after the internal reshapes.
+masks and ORs on int32 words; no MXU involvement.  Output byte ``(p, c)``
+gathers bit ``p`` of elements ``8c .. 8c+7``, so the wrapper hands the
+kernel each element as one int32 word laid out ``(8, N/8)``: row ``k``
+holds elements ``8c+k``.  The group-of-8 then runs along sublanes, every
+op in the kernel is a 2-D elementwise op on lane-dense tiles, and the
+kernel needs no reshape and no reduction (Mosaic lowers neither for this
+layout).  A grid step moves ``block_n`` elements: an ``(8, block_n/8)``
+int32 tile in and an ``(8*itemsize, block_n/8)`` uint8 tile out.
 """
 
 from __future__ import annotations
@@ -26,63 +29,78 @@ __all__ = ["bitshuffle", "bitunshuffle"]
 _DEF_BLOCK = 8192  # elements per grid step
 
 
-def _bitshuffle_kernel(x_ref, o_ref):
-    x = x_ref[...]                                   # (bn, I) uint8
-    bn, itemsize = x.shape
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (x[:, :, None] >> shifts[None, None, :]) & jnp.uint8(1)
-    bits = bits.reshape(bn, itemsize * 8).T          # (8I, bn)
-    grp = bits.reshape(itemsize * 8, bn // 8, 8).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << shifts.astype(jnp.uint32))[None, None, :]
-    o_ref[...] = jnp.sum(grp * weights, axis=-1).astype(jnp.uint8)
+def _bitshuffle_kernel(w_ref, o_ref):
+    w = w_ref[...]                                   # (8, bc) int32 words
+    nbits, bc = o_ref.shape
+    plane = jax.lax.broadcasted_iota(jnp.int32, (nbits, bc), 0)
+    acc = jnp.zeros((nbits, bc), jnp.int32)
+    for k in range(8):                               # element 8c+k -> bit k
+        acc = acc | (((w[k:k + 1, :] >> plane) & 1) << k)
+    o_ref[...] = acc.astype(jnp.uint8)
 
 
 def _bitunshuffle_kernel(y_ref, o_ref):
-    y = y_ref[...]                                   # (8I, bn//8) uint8
-    nbits, bn8 = y.shape
-    itemsize = nbits // 8
-    bn = bn8 * 8
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (y[:, :, None] >> shifts[None, None, :]) & jnp.uint8(1)
-    bits = bits.reshape(nbits, bn).T                 # (bn, 8I)
-    grp = bits.reshape(bn, itemsize, 8).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << shifts.astype(jnp.uint32))[None, None, :]
-    o_ref[...] = jnp.sum(grp * weights, axis=-1).astype(jnp.uint8)
+    y = y_ref[...].astype(jnp.int32)                 # (8I, bc) bit planes
+    nbits, bc = y.shape
+    elem = jax.lax.broadcasted_iota(jnp.int32, (8, bc), 0)
+    acc = jnp.zeros((8, bc), jnp.int32)
+    for p in range(nbits):                           # plane p -> word bit p
+        acc = acc | (((y[p:p + 1, :] >> elem) & 1) << p)
+    o_ref[...] = acc
+
+
+def _words(x: jnp.ndarray) -> jnp.ndarray:
+    """(N, itemsize) uint8 -> (8, N/8) int32: element 8c+k at [k, c]."""
+    n, itemsize = x.shape
+    if itemsize == 1:
+        w = x.reshape(n)
+    else:
+        w = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * itemsize}"))
+    return w.astype(jnp.int32).reshape(n // 8, 8).T
+
+
+def _unwords(t: jnp.ndarray, itemsize: int) -> jnp.ndarray:
+    """Inverse of :func:`_words`."""
+    w = t.T.reshape(-1).astype(jnp.dtype(f"uint{8 * itemsize}"))
+    return jax.lax.bitcast_convert_type(w, jnp.uint8).reshape(-1, itemsize)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def bitshuffle(x: jnp.ndarray, *, block_n: int = _DEF_BLOCK,
-               interpret: bool = True) -> jnp.ndarray:
-    """(N, itemsize) uint8 -> (8*itemsize, N//8) uint8.  N % block_n == 0."""
+               interpret: bool = False) -> jnp.ndarray:
+    """(N, itemsize) uint8 -> (8*itemsize, N//8) uint8.  N % block_n == 0;
+    itemsize <= 4."""
     n, itemsize = x.shape
     block_n = min(block_n, n)
-    assert n % block_n == 0 and block_n % 8 == 0, (n, block_n)
-    grid = (n // block_n,)
+    assert n % block_n == 0 and block_n % 8 == 0 and itemsize <= 4, \
+        (n, block_n, itemsize)
+    bc = block_n // 8
     return pl.pallas_call(
         _bitshuffle_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_n, itemsize), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((8 * itemsize, block_n // 8), lambda i: (0, i)),
+        grid=(n // block_n,),
+        in_specs=[pl.BlockSpec((8, bc), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((8 * itemsize, bc), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((8 * itemsize, n // 8), jnp.uint8),
         interpret=interpret,
-    )(x)
+    )(_words(x))
 
 
 @functools.partial(jax.jit, static_argnames=("itemsize", "block_n", "interpret"))
 def bitunshuffle(y: jnp.ndarray, itemsize: int, *, block_n: int = _DEF_BLOCK,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool = False) -> jnp.ndarray:
     """(8*itemsize, N//8) uint8 -> (N, itemsize) uint8."""
     nbits, nover8 = y.shape
-    assert nbits == 8 * itemsize
+    assert nbits == 8 * itemsize and itemsize <= 4
     n = nover8 * 8
     block_n = min(block_n, n)
     assert n % block_n == 0 and block_n % 8 == 0
-    grid = (n // block_n,)
-    return pl.pallas_call(
+    bc = block_n // 8
+    t = pl.pallas_call(
         _bitunshuffle_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((nbits, block_n // 8), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((block_n, itemsize), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, itemsize), jnp.uint8),
+        grid=(n // block_n,),
+        in_specs=[pl.BlockSpec((nbits, bc), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((8, bc), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((8, nover8), jnp.int32),
         interpret=interpret,
     )(y)
+    return _unwords(t, itemsize)

@@ -30,7 +30,7 @@ def _t_kernel(x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def byteshuffle(x: jnp.ndarray, *, block_n: int = _DEF_BLOCK,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """(N, itemsize) uint8 -> (itemsize, N) uint8."""
     n, itemsize = x.shape
     block_n = min(block_n, n)
@@ -47,7 +47,7 @@ def byteshuffle(x: jnp.ndarray, *, block_n: int = _DEF_BLOCK,
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def byteunshuffle(y: jnp.ndarray, *, block_n: int = _DEF_BLOCK,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool = False) -> jnp.ndarray:
     """(itemsize, N) uint8 -> (N, itemsize) uint8."""
     itemsize, n = y.shape
     block_n = min(block_n, n)

@@ -18,10 +18,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["delta_block", "undelta_block"]
 
 _DEF_BLOCK = 4096
+_LANES = 128
 
 
 def _delta_kernel(x_ref, o_ref):
@@ -31,12 +33,31 @@ def _delta_kernel(x_ref, o_ref):
 
 
 def _undelta_kernel(d_ref, o_ref):
-    o_ref[...] = jnp.cumsum(d_ref[...], dtype=d_ref.dtype)
+    # inclusive prefix sum of a row-major (rows, 128) block by log-step
+    # shifted adds (Hillis-Steele): along the lanes, then the row totals
+    # down the rows.  Mosaic lowers no cumsum and no 1-D shift that leaves
+    # the first tile; lane and sublane rolls it does lower.
+    x = d_ref[...]
+    rows, lanes = x.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    zero = jnp.zeros_like(x)
+    s = 1
+    while s < lanes:
+        x = x + jnp.where(lane >= s, pltpu.roll(x, s, 1), zero)
+        s *= 2
+    tot = jnp.broadcast_to(x[:, lanes - 1:], x.shape)
+    carry = tot
+    s = 1
+    while s < rows:
+        carry = carry + jnp.where(row >= s, pltpu.roll(carry, s, 0), zero)
+        s *= 2
+    o_ref[...] = x + (carry - tot)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def delta_block(x: jnp.ndarray, *, block_n: int = _DEF_BLOCK,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """Block-local delta of a 1-D unsigned-int array; N % block_n == 0."""
     (n,) = x.shape
     block_n = min(block_n, n)
@@ -53,16 +74,18 @@ def delta_block(x: jnp.ndarray, *, block_n: int = _DEF_BLOCK,
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def undelta_block(d: jnp.ndarray, *, block_n: int = _DEF_BLOCK,
-                  interpret: bool = True) -> jnp.ndarray:
-    """Block-local inclusive cumsum (inverse of delta_block)."""
+                  interpret: bool = False) -> jnp.ndarray:
+    """Block-local inclusive cumsum (inverse of delta_block).
+    N % block_n == 0 and block_n % 128 == 0."""
     (n,) = d.shape
     block_n = min(block_n, n)
-    assert n % block_n == 0
+    assert n % block_n == 0 and block_n % _LANES == 0, (n, block_n)
+    rows = block_n // _LANES
     return pl.pallas_call(
         _undelta_kernel,
         grid=(n // block_n,),
-        in_specs=[pl.BlockSpec((block_n,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), d.dtype),
+        in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // _LANES, _LANES), d.dtype),
         interpret=interpret,
-    )(d)
+    )(d.reshape(n // _LANES, _LANES)).reshape(n)

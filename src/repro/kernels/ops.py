@@ -1,9 +1,10 @@
 """Public jit'd entry points for the Pallas kernels.
 
 These present *global* semantics (exactly ``ref.py``) on top of the
-block-parallel kernels, handle padding/viewing arbitrary tensors as byte
-streams, and pick interpret-vs-compiled automatically (interpret on CPU —
-this container — compiled on real TPU).
+block-parallel kernels and handle padding/viewing arbitrary tensors as byte
+streams.  Every entry point compiles for the TPU unless the caller passes
+``interpret=True`` (the CPU tests do); there is no silent fallback, so a
+run without a TPU fails instead of timing the Pallas interpreter.
 
 The composition the compressed-collective path uses::
 
@@ -35,17 +36,11 @@ from . import qpack as _qp
 from . import ref
 
 __all__ = [
-    "default_interpret",
     "bitshuffle_bytes", "bitunshuffle_bytes",
     "byteshuffle_bytes", "byteunshuffle_bytes",
     "delta_u32", "undelta_u32",
     "quantize_int8", "dequantize_int8",
 ]
-
-
-def default_interpret() -> bool:
-    """interpret=True unless we are actually on TPU."""
-    return jax.default_backend() != "tpu"
 
 
 def _pick_block(n: int, pref: int, mult: int) -> int:
@@ -72,10 +67,9 @@ def _as_byte_matrix(x: jnp.ndarray, itemsize: int) -> jnp.ndarray:
     return u8
 
 
-def bitshuffle_bytes(x: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
+def bitshuffle_bytes(x: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:
     """Bit-plane transpose of any tensor whose element count is a multiple
     of 8; returns (8*itemsize, N//8) uint8."""
-    interpret = default_interpret() if interpret is None else interpret
     itemsize = x.dtype.itemsize
     mat = _as_byte_matrix(x, itemsize)
     n = mat.shape[0]
@@ -84,8 +78,7 @@ def bitshuffle_bytes(x: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarr
 
 
 def bitunshuffle_bytes(y: jnp.ndarray, dtype, n_elems: int,
-                       interpret: bool | None = None) -> jnp.ndarray:
-    interpret = default_interpret() if interpret is None else interpret
+                       interpret: bool = False) -> jnp.ndarray:
     itemsize = jnp.dtype(dtype).itemsize
     block = _pick_block(n_elems, _bs._DEF_BLOCK, 8)
     mat = _bs.bitunshuffle(y, itemsize, block_n=block, interpret=interpret)
@@ -93,8 +86,7 @@ def bitunshuffle_bytes(y: jnp.ndarray, dtype, n_elems: int,
     return flat.reshape(n_elems)
 
 
-def byteshuffle_bytes(x: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
-    interpret = default_interpret() if interpret is None else interpret
+def byteshuffle_bytes(x: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:
     itemsize = x.dtype.itemsize
     mat = _as_byte_matrix(x, itemsize)
     block = _pick_block(mat.shape[0], _bys._DEF_BLOCK, 1)
@@ -102,8 +94,7 @@ def byteshuffle_bytes(x: jnp.ndarray, interpret: bool | None = None) -> jnp.ndar
 
 
 def byteunshuffle_bytes(y: jnp.ndarray, dtype, n_elems: int,
-                        interpret: bool | None = None) -> jnp.ndarray:
-    interpret = default_interpret() if interpret is None else interpret
+                        interpret: bool = False) -> jnp.ndarray:
     itemsize = jnp.dtype(dtype).itemsize
     block = _pick_block(n_elems, _bys._DEF_BLOCK, 1)
     mat = _bys.byteunshuffle(y, block_n=block, interpret=interpret)
@@ -114,10 +105,9 @@ def byteunshuffle_bytes(y: jnp.ndarray, dtype, n_elems: int,
 # delta with cross-block fix-up (global semantics == ref.delta_ref)
 # ---------------------------------------------------------------------------
 
-def delta_u32(x: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
+def delta_u32(x: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:
     """Global delta of a 1-D uint32/uint64 array via block-local kernel +
     O(n/block) boundary correction."""
-    interpret = default_interpret() if interpret is None else interpret
     (n,) = x.shape
     block = _pick_block(n, _delta._DEF_BLOCK, 1)
     d = _delta.delta_block(x, block_n=block, interpret=interpret)
@@ -128,17 +118,20 @@ def delta_u32(x: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
     return d.at[heads].subtract(x[heads - 1])
 
 
-def undelta_u32(d: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
-    """Global cumsum via block-local cumsum + carry propagation."""
-    interpret = default_interpret() if interpret is None else interpret
+def undelta_u32(d: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:
+    """Global cumsum via block-local cumsum + carry propagation.  The
+    kernel works on whole (rows, 128) tiles, so the stream is zero-padded
+    to a multiple of the block (trailing zeros leave the prefix intact)."""
     (n,) = d.shape
-    block = _pick_block(n, _delta._DEF_BLOCK, 1)
-    partial = _delta.undelta_block(d, block_n=block, interpret=interpret)
-    if block == n:
-        return partial
-    tails = partial[block - 1::block]                      # (n/block,)
-    carry = jnp.cumsum(tails, dtype=d.dtype) - tails       # exclusive
-    return partial + jnp.repeat(carry, block)
+    block = min(_delta._DEF_BLOCK, -(-n // 128) * 128)
+    m = -(-n // block) * block
+    partial = _delta.undelta_block(jnp.pad(d, (0, m - n)), block_n=block,
+                                   interpret=interpret)
+    if block < m:
+        tails = partial[block - 1::block]                  # (m/block,)
+        carry = jnp.cumsum(tails, dtype=d.dtype) - tails   # exclusive
+        partial = partial + jnp.repeat(carry, block)
+    return partial[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -146,13 +139,12 @@ def undelta_u32(d: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 def quantize_int8(x: jnp.ndarray, block_rows: int = 256,
-                  interpret: bool | None = None):
+                  interpret: bool = False):
     """Any-shape float tensor -> (int8 same-shape, f32 scales, orig shape).
 
     Rows of the internal (R, C) view are quantization groups; C is the
     trailing dim (or the whole tensor for 1-D).
     """
-    interpret = default_interpret() if interpret is None else interpret
     shape = x.shape
     mat = x.reshape(-1, shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
     r = mat.shape[0]
@@ -162,8 +154,7 @@ def quantize_int8(x: jnp.ndarray, block_rows: int = 256,
 
 
 def dequantize_int8(q: jnp.ndarray, s: jnp.ndarray, shape, dtype=jnp.float32,
-                    interpret: bool | None = None) -> jnp.ndarray:
-    interpret = default_interpret() if interpret is None else interpret
+                    interpret: bool = False) -> jnp.ndarray:
     block = _pick_block(q.shape[0], 256, 1)
     out = _qp.qunpack(q, s, dtype, block_rows=block, interpret=interpret)
     return out.reshape(shape)

@@ -42,7 +42,7 @@ def _qunpack_kernel(q_ref, s_ref, o_ref, *, dtype):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def qpack(x: jnp.ndarray, *, block_rows: int = _DEF_ROWS,
-          interpret: bool = True) -> tuple[jnp.ndarray, jnp.ndarray]:
+          interpret: bool = False) -> tuple[jnp.ndarray, jnp.ndarray]:
     """x: (R, C) float -> (int8 (R, C), f32 scale (R, 1)). R % block_rows == 0."""
     r, c = x.shape
     block_rows = min(block_rows, r)
@@ -66,7 +66,7 @@ def qpack(x: jnp.ndarray, *, block_rows: int = _DEF_ROWS,
 
 @functools.partial(jax.jit, static_argnames=("dtype", "block_rows", "interpret"))
 def qunpack(q: jnp.ndarray, scale: jnp.ndarray, dtype=jnp.float32, *,
-            block_rows: int = _DEF_ROWS, interpret: bool = True) -> jnp.ndarray:
+            block_rows: int = _DEF_ROWS, interpret: bool = False) -> jnp.ndarray:
     """Inverse of :func:`qpack` (lossy): q * scale, cast to ``dtype``."""
     r, c = q.shape
     block_rows = min(block_rows, r)

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, list_archs, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serve import ServeEngine
 
@@ -38,6 +39,7 @@ def main(argv=None) -> int:
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -2,11 +2,11 @@
 
 Wires every subsystem together: config registry -> model -> data pipeline
 (compressed BasketFile shards) -> sharded train step -> checkpoint manager
-(async, atomic, compressed) -> restart/resume.  On this CPU container it
-runs reduced configs (--reduced); on a real cluster the same driver takes
-the full config + production mesh.
+(async, atomic, compressed) -> restart/resume.  ``--reduced`` shrinks
+every dimension (CPU tests); ``--n-layers`` cuts depth only and keeps the
+published widths (one chip).
 
-Fault-tolerance drill (exercised by tests/test_train_driver.py):
+Fault-tolerance drill (exercised by tests/test_serve_driver.py):
   * kill the process at any step; re-running resumes from the latest
     checkpoint INCLUDING the data-pipeline cursor — no token skew;
   * --simulate-preempt N exits abruptly after N steps to make that drill
@@ -15,11 +15,14 @@ Fault-tolerance drill (exercised by tests/test_train_driver.py):
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-8b --reduced \
         --steps 200 --workdir /tmp/run1
+    PYTHONPATH=src python -m repro.launch.train --arch rwkv6-1.6b \
+        --n-layers 4 --batch 4 --seq-len 2048 --steps 6 --workdir run1
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -32,9 +35,10 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, list_archs, reduced
 from repro.data import TokenPipeline, write_token_shards
-from repro.launch.mesh import make_host_mesh
+from repro.io import cpu_count
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
-from repro.train import init_train_state, make_train_step
+from repro.train import abstract_train_state, init_train_state, make_train_step
 from repro.train.step import TrainState
 
 
@@ -56,6 +60,9 @@ def main(argv=None) -> int:
     ap.add_argument("--arch", default="qwen3-8b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true",
                     help="CPU-scale config (same structure)")
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="depth cut: keep this many layers (a multiple of "
+                         "the layer pattern) at the published widths")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128)
@@ -75,6 +82,14 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
+    if args.n_layers:
+        if args.n_layers % len(cfg.pattern):
+            ap.error(f"--n-layers {args.n_layers} is not a multiple of the "
+                     f"{len(cfg.pattern)}-layer pattern of {cfg.name}")
+        print(f"cut: {cfg.name} n_layers {cfg.n_layers} -> {args.n_layers} "
+              "(depth only; widths as configured)", flush=True)
+        cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
+    enable_compile_cache()
     model = Model(cfg)
 
     # ---- data: write shards once, stream with restart cursor
@@ -89,26 +104,34 @@ def main(argv=None) -> int:
     pipe = TokenPipeline(shards, batch=args.batch, seq_len=args.seq_len,
                          host_id=args.host_id, n_hosts=args.n_hosts)
 
-    # ---- state: fresh or resumed (elastic: works across device counts)
-    mgr = CheckpointManager(os.path.join(args.workdir, "ckpt"), keep=2)
-    state = init_train_state(model, jax.random.key(0),
-                             compress_grads=args.compress_grads)
+    # ---- state: fresh or resumed.  A resume builds its template from
+    # shapes alone and puts each tensor on the device as it decodes, so the
+    # device never holds a second (freshly initialised) train state.
+    mgr = CheckpointManager(os.path.join(args.workdir, "ckpt"), keep=2,
+                            workers=cpu_count())
     start_step = 0
     if mgr.latest_step() is not None:
-        tmpl = {"params": state.params, "opt": state.opt, "step": state.step,
-                "err": state.err}
-        tree, meta = mgr.restore(template=tmpl)
-        state = TrainState(params=tree["params"], opt=tree["opt"],
-                           step=jnp.asarray(tree["step"]), err=tree["err"])
+        abstract = abstract_train_state(model,
+                                        compress_grads=args.compress_grads)
+        tmpl = dict(vars(abstract))
+        dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        tree, meta = mgr.restore(template=tmpl,
+                                 shardings=jax.tree.map(lambda _: dev, tmpl))
+        state = TrainState(**tree)
         if "data_cursor" in meta:
             pipe.load_state_dict(meta["data_cursor"])
-        start_step = int(tree["step"])
+        start_step = int(state.step)
         print(f"resumed from step {start_step} (cursor {meta.get('data_cursor')})")
+    else:
+        state = init_train_state(model, jax.random.key(0),
+                                 compress_grads=args.compress_grads)
 
+    # the step donates the state: it updates in place, so the device holds
+    # one train state (plus temporaries), never the step's input and output
     step_fn = jax.jit(make_train_step(
         model, peak_lr=args.lr, warmup=max(args.steps // 20, 5),
         total_steps=args.steps, accum=args.accum,
-        compress_grads=args.compress_grads))
+        compress_grads=args.compress_grads), donate_argnums=0)
 
     log_path = os.path.join(args.workdir, "train_log.jsonl")
     t0 = time.monotonic()
@@ -128,9 +151,9 @@ def main(argv=None) -> int:
                 print(f"step {i+1:5d} loss={m['loss']:.4f} "
                       f"acc={m['accuracy']:.3f} {m['tok_per_s']:.0f} tok/s")
             if (i + 1) % args.ckpt_every == 0 or i + 1 == args.steps:
-                tree = {"params": state.params, "opt": state.opt,
-                        "step": state.step, "err": state.err}
-                mgr.save(i + 1, tree,
+                # the next step donates these buffers, so the save copies
+                # the state to the host first and compresses it from there
+                mgr.save(i + 1, dict(vars(state)), snapshot=True,
                          extra_meta={"data_cursor": pipe.state_dict(),
                                      "arch": cfg.name})
             if args.simulate_preempt and (i + 1) >= args.simulate_preempt \

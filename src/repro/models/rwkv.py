@@ -107,8 +107,8 @@ def rwkv_time_mix(p: dict, x: jnp.ndarray, cfg, chunk: int = 32,
     xr, xk, xv, xw, xg = (x + mu[i] * (xprev - x) for i in range(5))
 
     def proj(xi, w):
-        return _heads(constrain(jnp.einsum("bsd,de->bse", xi, w.astype(cdt)),
-                                ("dp", None, "tp")), H, D)
+        return constrain(_heads(jnp.einsum("bsd,de->bse", xi, w.astype(cdt)),
+                                H, D), ("dp", None, "tp", None))
 
     r = proj(xr, p["w_r"]).astype(jnp.float32)
     k = proj(xk, p["w_k"]).astype(jnp.float32)

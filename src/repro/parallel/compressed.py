@@ -27,17 +27,6 @@ from .actctx import _CTX
 __all__ = ["rowparallel_einsum_compressed"]
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: ``jax.shard_map``/``check_vma`` is the
-    new spelling, ``jax.experimental.shard_map``/``check_rep`` the old one."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _quantize_rows(x):
     """Per-(…, row) int8 quantization over the last dim (qpack_ref math)."""
     xf = x.astype(jnp.float32)
@@ -75,8 +64,8 @@ def rowparallel_einsum_compressed(y, w, out_dtype=None):
         out = jnp.einsum("kbsd,kbsu->bsd", qg.astype(jnp.float32), sg)
         return out.astype(out_dtype)
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_spec, None, tp), P(tp, None)),
-        out_specs=P(dp_spec, None, None),
+        out_specs=P(dp_spec, None, None), check_vma=False,
     )(y, w)

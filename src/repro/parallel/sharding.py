@@ -34,14 +34,9 @@ __all__ = ["ParallelismConfig", "abstract_mesh", "logical_to_pspec",
 
 
 def abstract_mesh(axis_sizes, axis_names) -> "jax.sharding.AbstractMesh":
-    """Version-portable AbstractMesh: newer jax takes (sizes, names), jax
-    0.4.x takes a tuple of (name, size) pairs.  Rules only read mesh shape,
-    so an abstract mesh is all the engine ever needs."""
-    AM = jax.sharding.AbstractMesh
-    try:
-        return AM(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AM(tuple(zip(axis_names, axis_sizes)))
+    """Device-free mesh of the given shape.  Rules only read mesh shape, so
+    an abstract mesh is all the engine ever needs."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -66,12 +66,6 @@ WIRE_DECODE_MBPS: dict[str, float] = {
     "repro-deflate-ref": 25.0,
     "lzma": 60.0,
 }
-if not _codec.HAVE_ZSTD:
-    # offline fallback: "zstd"/"zstd-fast" are backed by the pure-Python
-    # large-window engine (DESIGN.md §4) — the decision rule must rank
-    # what will actually run, not what the codec name suggests
-    WIRE_DECODE_MBPS["zstd"] = WIRE_DECODE_MBPS["repro-zstd"]
-    WIRE_DECODE_MBPS["zstd-fast"] = 40.0
 
 # The link speed assumed when the request doesn't declare one (MB/s —
 # ~10GbE).  Clients on slower links declare it per request; it shifts the

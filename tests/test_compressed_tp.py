@@ -19,7 +19,8 @@ def test_compressed_rowparallel_numerics():
 import jax, jax.numpy as jnp
 from repro.parallel.actctx import activation_context
 from repro.parallel.compressed import rowparallel_einsum_compressed
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=2, model=4)
 y = jax.random.normal(jax.random.key(0), (4, 16, 32), jnp.float32).astype(jnp.bfloat16)
 w = jax.random.normal(jax.random.key(1), (32, 24), jnp.float32) * 0.2
 ref = jnp.einsum("bse,ed->bsd", y.astype(jnp.float32), w)

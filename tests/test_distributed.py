@@ -35,8 +35,9 @@ def test_tiny_train_step_sharded_end_to_end():
         from repro.parallel import ParallelismConfig, param_shardings, opt_shardings, batch_shardings
         from repro.parallel.actctx import activation_context
         from repro.train.step import TrainState
+        from repro.launch.mesh import make_host_mesh
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh(data=4, model=2)
         cfg = reduced(get_config("qwen3-8b"))
         model = Model(cfg)
         pcfg = ParallelismConfig(zero3=True)
@@ -70,8 +71,9 @@ def test_decode_cache_time_sharding_flash_pattern():
         from repro.parallel import ParallelismConfig, param_shardings, cache_shardings
         from repro.parallel.actctx import activation_context
         import dataclasses
+        from repro.launch.mesh import make_host_mesh
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(data=2, model=4)
         cfg = dataclasses.replace(reduced(get_config("qwen3-8b")), n_kv_heads=2, n_heads=4)
         # kv=2 < model=4 -> time sharding kicks in
         model = Model(cfg)
@@ -109,7 +111,8 @@ def test_multipod_mesh_lowering():
         from repro.configs import get_config, reduced, SHAPES, ShapeSpec
         from repro.launch.specs import build_cell, parallelism_for
         from repro.parallel.actctx import activation_context
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = reduced(get_config("gemma2-9b"))
         shape = ShapeSpec("t", 64, 8, "train")
         cell = build_cell(cfg, shape, mesh, parallelism_for(cfg))
@@ -129,12 +132,13 @@ def test_elastic_restore_across_meshes():
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_pytree, load_pytree
-        m2 = jax.make_mesh((2, 1), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        m2 = make_host_mesh(data=2, model=1)
         tree = {"w": jax.device_put(jnp.arange(128.0).reshape(16, 8),
                                     NamedSharding(m2, P("data", None)))}
         td = tempfile.mkdtemp()
         save_pytree(os.path.join(td, "c.bskt"), tree)
-        m8 = jax.make_mesh((4, 2), ("data", "model"))
+        m8 = make_host_mesh(data=4, model=2)
         sh = {"w": NamedSharding(m8, P("data", "model"))}
         got, _ = load_pytree(os.path.join(td, "c.bskt"), template=tree, shardings=sh)
         assert got["w"].sharding == sh["w"]

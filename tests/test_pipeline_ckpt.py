@@ -125,7 +125,8 @@ def test_elastic_reshard_device_put(tmp_path):
     """Restore with explicit shardings (single-device here; the mesh case
     is exercised in test_distributed.py)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     sh = {"w": NamedSharding(mesh, P("data", None))}
     save_pytree(str(tmp_path / "e.bskt"), tree)
