@@ -100,15 +100,16 @@ def run_main(main, argv):
 
 class CompileLog:
     """Sums JAX's own compile events: backend compile-or-load seconds, and
-    persistent-cache hits and entries written (JAX writes an entry on a
-    miss whose compile is long enough to keep)."""
+    persistent-cache hits and misses (JAX writes an entry only for a miss
+    whose compile passes its minimum time and size, so misses bound the
+    entries written from above)."""
 
     def __init__(self):
         import jax
         self.seconds = 0.0
         self.programs = 0
         self.hits = 0
-        self.written = 0
+        self.misses = 0
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
 
@@ -121,12 +122,12 @@ class CompileLog:
         if event == "/jax/compilation_cache/cache_hits":
             self.hits += 1
         elif event == "/jax/compilation_cache/cache_misses":
-            self.written += 1
+            self.misses += 1
 
     def line(self, label):
         return (f"{label}: {self.programs} programs, {self.seconds:.1f}s "
                 f"compiling or loading, persistent cache {self.hits} hits, "
-                f"{self.written} entries written")
+                f"{self.misses} misses")
 
 
 def _mem(dev):
@@ -380,11 +381,19 @@ def phase_sharded():
     m4 = {k: float(v) for k, v in m4.items()}
     upd4 = jax.tree.map(lambda a, b: np.asarray(a) - b,
                         jax.device_get(new4.params), before)
+    compare_steps((m1, upd1), (m4, upd4))
+
+
+def compare_steps(want, got):
+    """Checks a step's (metrics, parameter update) against the one-chip
+    step's on the same state and batch; raises PhaseError on a mismatch."""
+    import jax
+    import numpy as np
+    (m1, upd1), (m4, upd4) = want, got
     # bf16 compute: bf16 keeps 8 significant bits, so one ulp is up to
     # 2^-7 relative.  The loss is reduced in f32 from bf16 logits: one ulp.
     # The gradient norm sums bf16 matmul partials that the two layouts
-    # round in different places: four ulps.  A lost or doubled reduction
-    # across the mesh moves either by tens of percent.
+    # round in different places: four ulps.
     for k, tol in (("loss", 2 ** -7), ("grad_norm", 2 ** -5)):
         rel = abs(m4[k] - m1[k]) / abs(m1[k])
         log(f"  {k}: one chip {m1[k]:.6f}, sharded {m4[k]:.6f}, rel {rel:.2e}")

@@ -35,7 +35,6 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, list_archs, reduced
 from repro.data import TokenPipeline, write_token_shards
-from repro.io import cpu_count
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.train import abstract_train_state, init_train_state, make_train_step
@@ -107,8 +106,7 @@ def main(argv=None) -> int:
     # ---- state: fresh or resumed.  A resume builds its template from
     # shapes alone and puts each tensor on the device as it decodes, so the
     # device never holds a second (freshly initialised) train state.
-    mgr = CheckpointManager(os.path.join(args.workdir, "ckpt"), keep=2,
-                            workers=cpu_count())
+    mgr = CheckpointManager(os.path.join(args.workdir, "ckpt"), keep=2)
     start_step = 0
     if mgr.latest_step() is not None:
         abstract = abstract_train_state(model,
@@ -118,6 +116,7 @@ def main(argv=None) -> int:
         tree, meta = mgr.restore(template=tmpl,
                                  shardings=jax.tree.map(lambda _: dev, tmpl))
         state = TrainState(**tree)
+        del tree          # a second name would keep the restored state alive
         if "data_cursor" in meta:
             pipe.load_state_dict(meta["data_cursor"])
         start_step = int(state.step)
