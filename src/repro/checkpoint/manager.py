@@ -30,10 +30,20 @@ Fault-tolerance invariants:
     materializes alongside the device copy.
   * **retention**: ``keep`` most recent checkpoints are kept, the rest
     garbage-collected after a successful save.
+
+Phases: every stage of a save and a restore is a ``ckpt.<phase>`` span
+(:func:`_phase`) whose seconds also land in ``ckpt.phase_s{phase=...}``:
+``save`` (around ``write_branch`` per tensor and ``commit``: TOC, fsync,
+rename, directory fsync), ``snapshot`` (the host copy of
+``save(snapshot=True)``), ``manifest``, ``gc``; ``load`` (around ``open``:
+container, TOC and ``__meta__``; ``read_branch`` and ``device_put`` per
+tensor).  Each basket's precondition, codec, checksum and I/O stages are
+timed underneath them (``basket.stage_s``, :mod:`repro.core.basket`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import logging
@@ -57,6 +67,16 @@ _LOG = logging.getLogger("repro.checkpoint")
 __all__ = ["CheckpointManager", "save_pytree", "load_pytree"]
 
 _TARGET_BASKET_BYTES = 1 << 20
+
+
+@contextlib.contextmanager
+def _phase(name: str, **args):
+    """The span ``ckpt.<name>``, its seconds also in the histogram
+    ``ckpt.phase_s{phase=<name>}``: the ring is drained by its readers,
+    the histogram's sums stay."""
+    with obs.trace.span("ckpt." + name, cat="ckpt", **args), \
+            obs.histogram("ckpt.phase_s", phase=name).time():
+        yield
 
 
 def _flatten_with_paths(tree) -> dict[str, Any]:
@@ -225,10 +245,8 @@ def save_pytree(path: str, tree, profile: str = "checkpoint",
             return lambda: setattr(tuner, "engine", None)
         return lambda: None
 
-    t0 = time.perf_counter()
     if producers <= 1:
-        with obs.trace.span("ckpt.save", cat="ckpt", path=path,
-                            branches=len(flat)), \
+        with _phase("save", path=path, branches=len(flat)), \
                 obs.profile.mem_phase("ckpt.save"), \
                 BasketWriter(path, workers=workers, tuner=tuner,
                              parity=parity) as w:
@@ -236,16 +254,15 @@ def save_pytree(path: str, tree, profile: str = "checkpoint",
             try:
                 for name in flat:
                     dtype, shape, chunks, cfg = branch_args(name)
-                    with obs.trace.span("ckpt.write_branch", cat="ckpt",
-                                        branch=name):
+                    with _phase("write_branch", branch=name):
                         _entry_stats(stats, w.write_branch_chunks(
                             name, dtype=dtype, shape=shape, chunks=chunks,
                             cfg=cfg))
                 w.write_blob("__meta__", meta_blob)
+                with _phase("commit"):
+                    w.close()
             finally:
                 unlend()
-        obs.histogram("ckpt.save_s").observe(time.perf_counter() - t0)
-        obs.counter("ckpt.saves").inc()
         return stats
 
     from repro.io.merger import BufferMerger
@@ -253,8 +270,7 @@ def save_pytree(path: str, tree, profile: str = "checkpoint",
     shards = [names[i::producers] for i in range(producers)]
     errors: list = []
     lock = threading.Lock()
-    with obs.trace.span("ckpt.save", cat="ckpt", path=path,
-                        branches=len(flat)), \
+    with _phase("save", path=path, branches=len(flat)), \
             obs.profile.mem_phase("ckpt.save"), \
             BufferMerger(path, workers=workers, tuner=tuner,
                          parity=parity) as m:
@@ -265,8 +281,7 @@ def save_pytree(path: str, tree, profile: str = "checkpoint",
                 for name in shard:
                     buf = m.buffer()
                     dtype, shape, chunks, cfg = branch_args(name)
-                    with obs.trace.span("ckpt.write_branch", cat="ckpt",
-                                        branch=name):
+                    with _phase("write_branch", branch=name):
                         entry = buf.write_branch_chunks(
                             name, dtype=dtype, shape=shape, chunks=chunks,
                             cfg=cfg)
@@ -290,8 +305,8 @@ def save_pytree(path: str, tree, profile: str = "checkpoint",
         buf = m.buffer()
         buf.write_blob("__meta__", meta_blob)
         m.merge(buf)
-    obs.histogram("ckpt.save_s").observe(time.perf_counter() - t0)
-    obs.counter("ckpt.saves").inc()
+        with _phase("commit"):
+            m.close()
     return stats
 
 
@@ -312,26 +327,27 @@ def load_pytree(path: str, template=None, shardings=None, workers: int = 4,
     from the ``<path>.parity`` sidecar (when one exists) before the read
     fails — the restore-side half of ``save_pytree(parity=k)``."""
     flat_s = _flatten_with_paths(shardings) if shardings is not None else {}
-    t0 = time.perf_counter()
-    with obs.trace.span("ckpt.load", cat="ckpt", path=path), \
-            obs.profile.mem_phase("ckpt.load"), \
-            BasketFile(path, workers=workers, prefetch=prefetch,
-                       heal=heal) as f:
-        meta = json.loads(bytes(f.read_branch("__meta__")).decode())
+    with _phase("load", path=path), obs.profile.mem_phase("ckpt.load"), \
+            contextlib.ExitStack() as files:
+        with _phase("open"):
+            f = files.enter_context(BasketFile(
+                path, workers=workers, prefetch=prefetch, heal=heal))
+            meta = json.loads(bytes(f.read_branch("__meta__")).decode())
         bf16 = set(meta.get("bf16", []))
 
         def read(name):
-            with obs.trace.span("ckpt.read_branch", cat="ckpt", branch=name):
+            with _phase("read_branch", branch=name):
                 arr = f.read_branch(name, workers=workers)
             if name in bf16:
                 arr = arr.view(jax.numpy.bfloat16.dtype)
             sh = flat_s.get(name)
+            if sh is None:
+                return arr
             # staging symmetry: put each branch on device now, free host
-            return jax.device_put(arr, sh) if sh is not None else arr
+            with _phase("device_put"):
+                return jax.device_put(arr, sh)
 
         flat = {n: read(n) for n in f.branch_names() if n != "__meta__"}
-    obs.histogram("ckpt.load_s").observe(time.perf_counter() - t0)
-    obs.counter("ckpt.loads").inc()
     if template is None:
         return flat, meta
 
@@ -407,9 +423,11 @@ class CheckpointManager:
                 except Exception:
                     pass            # unreadable/malformed header: just re-tune
         if snapshot:
-            src = jax.tree.map(
-                lambda x: None if x is None else np.asarray(jax.device_get(x)),
-                tree, is_leaf=lambda x: x is None)
+            with _phase("snapshot"):
+                src = jax.tree.map(
+                    lambda x: None if x is None
+                    else np.asarray(jax.device_get(x)),
+                    tree, is_leaf=lambda x: x is None)
         else:
             src = tree
 
@@ -425,26 +443,11 @@ class CheckpointManager:
                                     parity=self.parity)
                 manifest = {"step": step, "time": time.time(),
                             "wall_s": time.monotonic() - t0, **stats}
-                # atomic commit: tmp + fsync + rename + fsync dir — the
-                # manifest is the "this step exists" marker, so it must
-                # never be observable half-written (or survive a crash
-                # pointing at a container the kernel never flushed)
-                tmp = self._manifest_path(step) + ".tmp"
-                try:
-                    with open(tmp, "w") as fh:
-                        json.dump(manifest, fh)
-                        fh.flush()
-                        os.fsync(fh.fileno())
-                    os.replace(tmp, self._manifest_path(step))
-                except BaseException:
-                    try:
-                        os.remove(tmp)
-                    except OSError:
-                        pass
-                    raise
-                _fsync_dir(self.dir)
+                with _phase("manifest"):
+                    self._write_manifest(step, manifest)
                 self._last_stats = manifest
-                self._gc()
+                with _phase("gc"):
+                    self._gc()
             except BaseException as e:   # surfaced by the next save()/wait()
                 self._error = e
 
@@ -452,6 +455,26 @@ class CheckpointManager:
         self._worker.start()
         if wait:
             self.wait()
+
+    def _write_manifest(self, step: int, manifest: dict) -> None:
+        # atomic commit: tmp + fsync + rename + fsync dir — the manifest
+        # is the "this step exists" marker, so it must never be observable
+        # half-written (or survive a crash pointing at a container the
+        # kernel never flushed)
+        tmp = self._manifest_path(step) + ".tmp"
+        try:
+            with open(tmp, "w") as fh:
+                json.dump(manifest, fh)
+                fh.flush()
+                os.fsync(fh.fileno())
+            os.replace(tmp, self._manifest_path(step))
+        except BaseException:
+            try:
+                os.remove(tmp)
+            except OSError:
+                pass
+            raise
+        _fsync_dir(self.dir)
 
     def wait(self) -> Optional[dict]:
         """Join any in-flight save; re-raises a background-save failure (a

@@ -9,6 +9,13 @@ decompressed in isolation — that independence is what enables the paper's
 Basket metadata also carries an adler32 of the uncompressed bytes
 (vectorized implementation — the CF-ZLIB checksum path), verified on read.
 
+Every basket's stages are timed into ``basket.stage_s{op=pack|unpack,
+stage=precond|codec|checksum|io}`` (:func:`repro.obs.trace.timed`: a
+profiler annotation, never a ring event), with its raw bytes in
+``basket.stage_bytes{op=...}``.  The codec and inverse-precondition
+stages of a decode are timed in :mod:`repro.core.codec`, the file I/O
+stages by the container (:mod:`repro.core.bfile`, :mod:`repro.io.engine`).
+
 Zero-copy data plane: ``split_array`` yields buffer-protocol *views* of the
 source array (no per-basket ``tobytes()``), ``pack_basket`` accepts any
 buffer-protocol object, and ``unpack_basket_into`` decodes a basket directly
@@ -23,6 +30,8 @@ import dataclasses
 from typing import Optional
 
 import numpy as np
+
+from repro import obs
 
 from . import codec as _codec
 from .checksum import adler32_hw
@@ -91,9 +100,14 @@ def pack_basket(raw, cfg: _codec.CompressionConfig,
     returned payload is bytes-like; for the ``none``/``none`` identity
     configuration it may alias ``raw`` itself."""
     from . import precond as _precond
-    staged = _precond.apply_precond(cfg.precond, raw) if cfg.precond != "none" else raw
-    payload = _codec.get_codec(cfg.algo).compress(staged, cfg.level, cfg.dictionary) \
-        if cfg.enabled else staged
+    with obs.trace.timed("basket.stage_s", op="pack", stage="precond"):
+        staged = _precond.apply_precond(cfg.precond, raw) \
+            if cfg.precond != "none" else raw
+    with obs.trace.timed("basket.stage_s", op="pack", stage="codec"):
+        payload = _codec.get_codec(cfg.algo).compress(
+            staged, cfg.level, cfg.dictionary) if cfg.enabled else staged
+    with obs.trace.timed("basket.stage_s", op="pack", stage="checksum"):
+        checksum = adler32_hw(raw)
     meta = BasketMeta(
         algo=cfg.algo if cfg.enabled else "none",
         level=cfg.level if cfg.enabled else 0,
@@ -101,11 +115,12 @@ def pack_basket(raw, cfg: _codec.CompressionConfig,
         orig_len=_nbytes(raw),
         stored_len=_nbytes(staged),
         comp_len=_nbytes(payload),
-        checksum=adler32_hw(raw),
+        checksum=checksum,
         entry_start=entry_start,
         entry_count=entry_count,
         has_dict=cfg.dictionary is not None,
     )
+    obs.counter("basket.stage_bytes", op="pack").inc(meta.orig_len)
     return payload, meta
 
 
@@ -127,9 +142,17 @@ def unpack_basket(payload: bytes, meta: BasketMeta,
     raw = _codec.decompress(payload, meta.orig_len, cfg, stored_len=meta.stored_len)
     if len(raw) != meta.orig_len:
         raise ValueError(f"basket decoded {len(raw)} bytes, expected {meta.orig_len}")
-    if verify and adler32_hw(raw) != meta.checksum:
-        raise ChecksumError("basket checksum mismatch (corrupt data)")
+    if verify:
+        _verify(raw, meta)
+    obs.counter("basket.stage_bytes", op="unpack").inc(meta.orig_len)
     return raw
+
+
+def _verify(raw, meta: BasketMeta) -> None:
+    with obs.trace.timed("basket.stage_s", op="unpack", stage="checksum"):
+        ok = adler32_hw(raw) == meta.checksum
+    if not ok:
+        raise ChecksumError("basket checksum mismatch (corrupt data)")
 
 
 def unpack_basket_into(payload, meta: BasketMeta, out,
@@ -152,8 +175,9 @@ def unpack_basket_into(payload, meta: BasketMeta, out,
                                stored_len=meta.stored_len)
     if n != meta.orig_len:
         raise ValueError(f"basket decoded {n} bytes, expected {meta.orig_len}")
-    if verify and adler32_hw(dst) != meta.checksum:
-        raise ChecksumError("basket checksum mismatch (corrupt data)")
+    if verify:
+        _verify(dst, meta)
+    obs.counter("basket.stage_bytes", op="unpack").inc(meta.orig_len)
     return n
 
 

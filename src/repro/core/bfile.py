@@ -32,6 +32,8 @@ from typing import Optional
 import numpy as np
 import zstandard
 
+from repro import obs
+
 from .basket import (BasketMeta, ChecksumError, byte_offsets, join_baskets,
                      split_array, unpack_basket, unpack_basket_into)
 from .checksum import adler32_hw
@@ -42,6 +44,12 @@ def _pread(path: str, offset: int, n: int, expect=None) -> bytes:
     # lazy import: repro.io imports repro.core at package-init time
     from repro.io import fdcache
     return fdcache.pread(path, offset, n, expect=expect)
+
+
+def _timed_io(op: str):
+    """A basket's file I/O stage, ``basket.stage_s{op=<op>,stage=io}``."""
+    return obs.trace.timed("basket.stage_s", op=op, stage="io")
+
 
 __all__ = ["BasketWriter", "BasketFile", "write_arrays", "read_arrays",
            "CorruptBasketError", "TruncatedContainerError",
@@ -256,7 +264,8 @@ class BasketWriter:
             packed = engine.pack_stream(chunks, cfg)
             for _start, _count, payload, meta in packed:
                 off = self._f.tell()
-                self._f.write(payload)  # accepts memoryview payloads zero-copy
+                with _timed_io("pack"):
+                    self._f.write(payload)  # memoryview payloads zero-copy
                 if self._tuner is not None:
                     self._tuner.observe(name, meta)     # drift-detector feed
                 if self._parity is not None:
@@ -281,7 +290,8 @@ class BasketWriter:
         try:
             for payload, meta_json in baskets:
                 off = self._f.tell()
-                self._f.write(payload)
+                with _timed_io("pack"):
+                    self._f.write(payload)
                 if self._parity is not None:
                     self._parity.add(name, len(entry["baskets"]), payload)
                 entry["baskets"].append({"offset": off, "meta": dict(meta_json)})
@@ -509,8 +519,9 @@ class BasketFile:
         b = entry["baskets"][i]
         meta = BasketMeta.from_json(b["meta"])
         try:
-            payload = _pread(self.path, b["offset"], meta.comp_len,
-                             expect=self.generation)
+            with _timed_io("unpack"):
+                payload = _pread(self.path, b["offset"], meta.comp_len,
+                                 expect=self.generation)
             return unpack_basket(payload, meta, self._dictionary(entry),
                                  verify=self.verify)
         except ChecksumError as e:
@@ -531,8 +542,9 @@ class BasketFile:
         b = entry["baskets"][i]
         meta = BasketMeta.from_json(b["meta"])
         try:
-            payload = _pread(self.path, b["offset"], meta.comp_len,
-                             expect=self.generation)
+            with _timed_io("unpack"):
+                payload = _pread(self.path, b["offset"], meta.comp_len,
+                                 expect=self.generation)
             return unpack_basket_into(payload, meta, out,
                                       self._dictionary(entry),
                                       verify=self.verify)

@@ -35,6 +35,8 @@ import lzma
 import zlib
 from typing import Callable, Optional
 
+from repro import obs
+
 from . import lz4 as _lz4
 from . import precond as _precond
 from . import repro_deflate as _rdef
@@ -238,10 +240,18 @@ def decompress(comp: bytes, orig_len: int, cfg: CompressionConfig,
     """
     if stored_len is None:
         stored_len = orig_len
-    buf = comp if not cfg.enabled else get_codec(cfg.algo).decompress(comp, stored_len, cfg.dictionary)
+    buf = _decode(comp, stored_len, cfg)
     if cfg.precond != "none":
-        buf = _precond.undo_precond(cfg.precond, buf, orig_len)
+        with obs.trace.timed("basket.stage_s", op="unpack", stage="precond"):
+            buf = _precond.undo_precond(cfg.precond, buf, orig_len)
     return buf
+
+
+def _decode(comp, stored_len: int, cfg: CompressionConfig):
+    """The codec stage of a decode (timed as a basket's ``unpack/codec``)."""
+    with obs.trace.timed("basket.stage_s", op="unpack", stage="codec"):
+        return comp if not cfg.enabled else get_codec(cfg.algo).decompress(
+            comp, stored_len, cfg.dictionary)
 
 
 def decompress_into(comp: bytes, orig_len: int, cfg: CompressionConfig, out,
@@ -256,5 +266,6 @@ def decompress_into(comp: bytes, orig_len: int, cfg: CompressionConfig, out,
     concatenation.  Returns the number of bytes written."""
     if stored_len is None:
         stored_len = orig_len
-    buf = comp if not cfg.enabled else get_codec(cfg.algo).decompress(comp, stored_len, cfg.dictionary)
-    return _precond.undo_precond_into(cfg.precond, buf, out, orig_len)
+    buf = _decode(comp, stored_len, cfg)
+    with obs.trace.timed("basket.stage_s", op="unpack", stage="precond"):
+        return _precond.undo_precond_into(cfg.precond, buf, out, orig_len)

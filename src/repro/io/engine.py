@@ -96,16 +96,14 @@ def _task_span(name: str, tp, **args):
 
 
 def _obs_pack(raw, cfg, start: int, count: int, tp=None):
-    """pack_basket with stage telemetry.  Runs in whichever worker executes
-    the task: thread workers hit the parent registry directly; process
-    workers hit their own, folded back by :meth:`CompressionEngine.collect_obs`."""
-    t0 = time.perf_counter()
+    """pack_basket with byte telemetry (its stages time themselves into
+    ``basket.stage_s``).  Runs in whichever worker executes the task:
+    thread workers hit the parent registry directly; process workers hit
+    their own, folded back by :meth:`CompressionEngine.collect_obs`."""
     with _task_span("engine.pack", tp, algo=cfg.algo), \
             obs.profile.mem_phase("engine.pack"):
         payload, meta = _basket.pack_basket(raw, cfg, entry_start=start,
                                             entry_count=count)
-    obs.histogram("engine.pack_s", algo=cfg.algo).observe(
-        time.perf_counter() - t0)
     obs.counter("engine.pack.bytes_in", algo=cfg.algo).inc(meta.orig_len)
     obs.counter("engine.pack.bytes_out", algo=cfg.algo).inc(meta.comp_len)
     return payload, meta
@@ -206,13 +204,14 @@ def _unpack_task(path: str, offset: int, meta_json: dict,
     meta = _basket.BasketMeta.from_json(meta_json)
     with _task_span("engine.unpack", tp, algo=meta.algo), \
             obs.profile.mem_phase("engine.unpack"):
-        payload = _fdcache.pread(path, offset, meta.comp_len, expect=ident)
-        t0 = time.perf_counter()
-        raw = _basket.unpack_basket(payload, meta, dictionary, verify=verify)
-    obs.histogram("engine.unpack_s", algo=meta.algo).observe(
-        time.perf_counter() - t0)
-    obs.counter("engine.unpack.bytes_out", algo=meta.algo).inc(meta.orig_len)
-    return raw
+        payload = _timed_pread(path, offset, meta.comp_len, ident)
+        return _basket.unpack_basket(payload, meta, dictionary, verify=verify)
+
+
+def _timed_pread(path: str, offset: int, n: int, ident) -> bytes:
+    """One basket's read, timed as its ``unpack/io`` stage."""
+    with obs.trace.timed("basket.stage_s", op="unpack", stage="io"):
+        return _fdcache.pread(path, offset, n, expect=ident)
 
 
 def _unpack_task_into(path: str, offset: int, meta_json: dict,
@@ -222,14 +221,9 @@ def _unpack_task_into(path: str, offset: int, meta_json: dict,
     destination slice — the thread-pool / serial scatter path)."""
     meta = _basket.BasketMeta.from_json(meta_json)
     with _task_span("engine.unpack", tp, algo=meta.algo):
-        payload = _fdcache.pread(path, offset, meta.comp_len, expect=ident)
-        t0 = time.perf_counter()
-        n = _basket.unpack_basket_into(payload, meta, out, dictionary,
-                                       verify=verify)
-    obs.histogram("engine.unpack_s", algo=meta.algo).observe(
-        time.perf_counter() - t0)
-    obs.counter("engine.unpack.bytes_out", algo=meta.algo).inc(meta.orig_len)
-    return n
+        payload = _timed_pread(path, offset, meta.comp_len, ident)
+        return _basket.unpack_basket_into(payload, meta, out, dictionary,
+                                          verify=verify)
 
 
 def _unpack_task_shm(path: str, offset: int, meta_json: dict,

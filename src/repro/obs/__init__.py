@@ -20,10 +20,14 @@ flag check plus a no-op call::
     from repro import obs
 
     obs.counter("server.reads", branch=name).inc()
-    with obs.histogram("engine.pack_s", algo=cfg.algo).time():
+    with obs.trace.timed("basket.stage_s", op="pack", stage="codec"):
         ...
     with obs.trace.span("ckpt.save", step=step):
         ...
+
+In a process that has imported JAX, spans and ``timed`` regions are also
+``jax.profiler.TraceAnnotation`` events, so a profiler trace holds them
+on the device's clock (:mod:`repro.obs.trace`).
 
 Default-on: instruments are live unless ``REPRO_OBS=off``.  The CI
 overhead gate (benchmarks/fig_obs.py) holds the instrumented fig_zerocopy

@@ -24,6 +24,14 @@ Timestamps are microseconds anchored to the unix epoch (wall clock
 sampled once at import, advanced by ``perf_counter_ns`` so the timeline
 is monotonic within a process).  Same-host captures therefore line up
 when stitched; cross-host skew is whatever NTP leaves behind.
+
+One clock with the device: in a process that has imported JAX, every
+span is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+under ``jax.profiler.trace`` the program's spans sit in the same
+``.xplane.pb`` as the device ops, on the profiler's clock.  Processes
+that never import JAX (``BasketServer``, codec pool workers) are not
+made to.  :func:`timed` is the per-basket counterpart: a histogram
+timer that is an annotation under a profiler and never a ring event.
 Thread-pool workers share the parent's ring; *process*-pool workers
 have their own ring that the engine folds back on ``collect_obs()``
 via :func:`drain` + :func:`ingest`.  When the ring is full each
@@ -36,6 +44,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -45,7 +54,7 @@ from repro.obs import context as _context
 from repro.obs import metrics as _metrics
 from repro.obs import profile as _profile
 
-__all__ = ["span", "instant", "drain", "events", "export_chrome",
+__all__ = ["span", "timed", "instant", "drain", "events", "export_chrome",
            "set_capacity", "clear", "ingest", "stitch", "build_tree"]
 
 _WALL_US = time.time_ns() / 1e3
@@ -73,6 +82,14 @@ def clear() -> None:
         _ring.clear()
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of ``name`` when JAX is already
+    imported, else None.  Outside a profiler session it records nothing
+    (about half a microsecond)."""
+    prof = sys.modules.get("jax.profiler")
+    return None if prof is None else prof.TraceAnnotation(name)
+
+
 def _note_thread() -> int:
     t = threading.current_thread()
     tid = t.ident or 0
@@ -93,7 +110,8 @@ def _append(ev: dict) -> None:
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "_t0", "_ctx", "_parent", "_prof")
+    __slots__ = ("name", "cat", "args", "_t0", "_ctx", "_parent", "_prof",
+                 "_ann")
 
     def __init__(self, name: str, cat: str, args: dict, root: bool):
         self.name = name
@@ -123,11 +141,16 @@ class _Span:
             _profile.note_push(
                 self.name,
                 self._ctx.trace_id if self._ctx is not None else "")
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = _now_us()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if self._prof:
             _profile.note_pop()
         if self._ctx is not None:
@@ -171,6 +194,40 @@ def span(name: str, cat: str = "repro", root: bool = False, **args):
     if not _metrics.enabled():
         return _NULL_SPAN
     return _Span(name, cat, args, root)
+
+
+class _Timed:
+    __slots__ = ("_h", "_ann", "_t0")
+
+    def __init__(self, h):
+        self._h = h
+
+    def __enter__(self):
+        self._ann = _annotation(self._h.key)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._h.observe(dt)
+
+
+def timed(name: str, **labels):
+    """Context manager timing a short, frequent region (one basket's
+    codec stage) into the histogram ``name{labels}``.
+
+    Under a running JAX profiler the region is also a ``TraceAnnotation``
+    named by the histogram's key, so the trace shows it on the device's
+    clock.  It records no ring event: one event per basket would flood
+    the ring (the rule the engine's task spans follow too).  No-op when
+    obs is disabled."""
+    if not _metrics.enabled():
+        return _NULL_SPAN
+    return _Timed(_metrics.REGISTRY.histogram(name, **labels))
 
 
 def instant(name: str, cat: str = "repro", **args) -> None:

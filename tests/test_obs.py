@@ -184,6 +184,79 @@ def test_disabled_returns_null_instrument():
     assert obs.enabled() == prev
 
 
+def _stage_delta(fn) -> tuple[dict, dict]:
+    """What ``fn`` adds to the basket stage telemetry: {(op, stage):
+    (count, seconds)} and {op: raw bytes}."""
+    before = obs.snapshot()
+    fn()
+    after = obs.snapshot()
+    stages, nbytes = {}, {}
+    for key, h in after["hists"].items():
+        name, lab = M.parse_key(key)
+        old = before["hists"].get(key, {"count": 0, "sum": 0.0})
+        if name == "basket.stage_s" and h["count"] > old["count"]:
+            stages[(lab["op"], lab["stage"])] = (h["count"] - old["count"],
+                                                 h["sum"] - old["sum"])
+    for key, n in after["counters"].items():
+        name, lab = M.parse_key(key)
+        if name == "basket.stage_bytes" and n > before["counters"].get(key, 0):
+            nbytes[lab["op"]] = n - before["counters"].get(key, 0)
+    return stages, nbytes
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_basket_stage_timers_label_each_stage(enabled):
+    """One pack_basket / unpack_basket_into round trip times each stage
+    once under its op/stage labels and counts its raw bytes once per op;
+    with obs disabled it records nothing."""
+    from repro.core.basket import pack_basket, unpack_basket_into
+    from repro.core.codec import CompressionConfig
+
+    raw = np.linspace(0.0, 1.0, 65_536, dtype=np.float32)
+    out = np.empty_like(raw)
+
+    def round_trip():
+        payload, meta = pack_basket(raw, CompressionConfig("zstd", 2,
+                                                           "bitshuffle4"))
+        unpack_basket_into(payload, meta, out)
+
+    prev = obs.set_enabled(enabled)
+    try:
+        stages, nbytes = _stage_delta(round_trip)
+    finally:
+        obs.set_enabled(prev)
+    assert np.array_equal(out, raw)
+    if not enabled:
+        assert stages == {} and nbytes == {}
+        return
+    assert set(stages) == {(op, st) for op in ("pack", "unpack")
+                           for st in ("precond", "codec", "checksum")}
+    assert all(n == 1 and t > 0 for n, t in stages.values())
+    assert nbytes == {"pack": raw.nbytes, "unpack": raw.nbytes}
+
+
+def test_container_times_basket_io_stages(tmp_path):
+    """A BasketWriter write and a BasketFile read time each basket's file
+    I/O as its pack/io and unpack/io stage."""
+    from repro.core.bfile import BasketFile, BasketWriter
+
+    arr = np.arange(3 * 65_536, dtype=np.float32)
+    path = str(tmp_path / "io.bskt")
+
+    def write():
+        with BasketWriter(path) as w:
+            w.write_branch("x", arr, target_basket_bytes=65_536 * 4)
+
+    def read():
+        with BasketFile(path) as f:
+            assert np.array_equal(f.read_branch("x"), arr)
+
+    stages, nbytes = _stage_delta(write)
+    assert stages[("pack", "io")][0] == 3 and nbytes == {"pack": arr.nbytes}
+    stages, nbytes = _stage_delta(read)
+    assert stages[("unpack", "io")][0] == 3 and nbytes == {"unpack": arr.nbytes}
+
+
 # ---------------------------------------------------------------------------
 # engine transports: thread pool, process+pickle, process+shm
 # ---------------------------------------------------------------------------
