@@ -236,7 +236,8 @@ def decompress(comp: bytes, orig_len: int, cfg: CompressionConfig,
 
     ``orig_len`` is the pre-preconditioner length; ``stored_len`` the
     post-preconditioner (= codec input) length.  They differ only for
-    bitshuffle with an element count not divisible by 8 (packbits padding).
+    bitshuffle with an element count not divisible by 8 (each bit plane is
+    zero-padded to whole bytes).
     """
     if stored_len is None:
         stored_len = orig_len

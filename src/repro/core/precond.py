@@ -14,6 +14,15 @@ All host-path transforms are pure numpy and exactly invertible:
 multiple of ``itemsize`` (remainder bytes are passed through untouched,
 matching Blosc semantics).
 
+BitShuffle transposes bits a word at a time: byte ``b`` of 8 consecutive
+elements is one uint64, transposed as an 8x8 bit matrix by three masked
+delta swaps, so no step expands the data to one byte per bit.  The stored
+format is the one the former ``unpackbits``/``packbits`` implementation
+wrote, byte for byte: bit plane ``8b + j`` (byte ``b``, bit ``j``, LSB
+first) of all N elements, packed LSB-first, ``ceil(N/8)`` bytes a plane,
+the last byte zero-padded.  Every step is a numpy ufunc or array copy, which
+release the interpreter lock, so decode threads run the inverse in parallel.
+
 The device path (Pallas TPU kernels) lives in ``repro.kernels``; this module
 is the reference implementation those kernels are tested against.
 """
@@ -93,6 +102,26 @@ def unshuffle(buf, itemsize: int = 4) -> bytes:
 # BitShuffle (bit transpose) — Blosc "bitshuffle"
 # ---------------------------------------------------------------------------
 
+# Bit 8*r + c of a word is bit c of its byte r.  Three delta swaps (Hacker's
+# Delight, 7-3) move it to bit 8*c + r: an 8x8 bit-matrix transpose, which is
+# its own inverse.
+_BIT_SWAPS = tuple((np.uint64(mask), np.uint64(shift)) for mask, shift in (
+    (0x00AA00AA00AA00AA, 7), (0x0000CCCC0000CCCC, 14), (0x00000000F0F0F0F0, 28)))
+
+
+def _transpose_bits8x8(words: np.ndarray) -> None:
+    """Transpose every little-endian uint64 of ``words`` as an 8x8 bit matrix,
+    in place."""
+    t = np.empty_like(words)
+    for mask, shift in _BIT_SWAPS:
+        np.right_shift(words, shift, out=t)
+        t ^= words
+        t &= mask
+        words ^= t
+        t <<= shift
+        words ^= t
+
+
 def bitshuffle(buf, itemsize: int = 4) -> bytes:
     """Bit-transpose within each block of ``itemsize`` elements' bits.
 
@@ -102,14 +131,51 @@ def bitshuffle(buf, itemsize: int = 4) -> bytes:
     """
     a = _as_bytes(buf)
     n = a.size - (a.size % itemsize)
-    body, tail = a[:n], a[n:]
-    if n == 0:
-        return tail.tobytes()
-    elems = body.reshape(-1, itemsize)                       # (N, itemsize)
-    bits = np.unpackbits(elems, axis=1, bitorder="little")   # (N, 8*itemsize)
-    bits_t = bits.T                                          # (8*itemsize, N)
-    out = np.packbits(bits_t, axis=1, bitorder="little")     # (8*itemsize, ceil(N/8))
-    return out.tobytes() + tail.tobytes()
+    n_elems = n // itemsize
+    per_bit = (n_elems + 7) // 8
+    # byte plane b: byte b of every element, zero-padded to whole words, so
+    # word c of a plane holds that byte of elements 8c..8c+7
+    planes = np.empty((itemsize, 8 * per_bit), np.uint8)
+    planes[:, :n_elems] = a[:n].reshape(n_elems, itemsize).T
+    planes[:, n_elems:] = 0
+    _transpose_bits8x8(planes.view("<u8"))
+    # byte j of word c now holds bit j of elements 8c..8c+7: gather each
+    # bit's bytes into its own plane
+    body = 8 * itemsize * per_bit
+    out = np.empty(body + a.size - n, np.uint8)
+    out[:body].reshape(itemsize, 8, per_bit)[...] = (
+        planes.reshape(itemsize, per_bit, 8).transpose(0, 2, 1))
+    out[body:] = a[n:]
+    return out.tobytes()
+
+
+def _bitshuffled_elems(size: int, itemsize: int, nbytes: int | None) -> int:
+    """Element count of a bitshuffled stream of ``size`` bytes."""
+    if nbytes is not None:
+        return nbytes // itemsize
+    # size = nbits * ceil(N/8) + tail with tail < itemsize; exact when N was
+    # a multiple of 8
+    nbits = 8 * itemsize
+    for t in range(itemsize):
+        if (size - t) % nbits == 0:
+            return (size - t) // nbits * 8
+    raise ValueError("cannot infer bitshuffle layout; pass nbytes")
+
+
+def _bitunshuffle_to(a: np.ndarray, itemsize: int, n_elems: int,
+                     o: np.ndarray) -> int:
+    """Write the inverse of :func:`bitshuffle` of ``a`` into ``o``."""
+    per_bit = (n_elems + 7) // 8
+    body = 8 * itemsize * per_bit
+    planes = np.empty((itemsize, per_bit, 8), np.uint8)
+    planes[...] = a[:body].reshape(itemsize, 8, per_bit).transpose(0, 2, 1)
+    planes = planes.reshape(itemsize, 8 * per_bit)
+    _transpose_bits8x8(planes.view("<u8"))
+    n = n_elems * itemsize
+    o[:n].reshape(n_elems, itemsize)[...] = planes[:, :n_elems].T
+    tail = a.size - body
+    o[n:n + tail] = a[body:]
+    return n + tail
 
 
 def bitunshuffle(buf, itemsize: int = 4, nbytes: int | None = None) -> bytes:
@@ -121,30 +187,11 @@ def bitunshuffle(buf, itemsize: int = 4, nbytes: int | None = None) -> bytes:
     records nbytes explicitly, so the None path is only a convenience).
     """
     a = _as_bytes(buf)
-    nbits = 8 * itemsize
-    if nbytes is None:
-        # total = nbits * ceil(N/8) + tail; assume tail < itemsize
-        per_bit = a.size // nbits if a.size % nbits == 0 else None
-        if per_bit is None:
-            # find split honouring tail < itemsize
-            for t in range(itemsize):
-                if (a.size - t) % nbits == 0:
-                    per_bit = (a.size - t) // nbits
-                    break
-            else:  # pragma: no cover - malformed input
-                raise ValueError("cannot infer bitshuffle layout; pass nbytes")
-            nbytes = per_bit * nbits - 0  # may overestimate N padding
-        n_elems = per_bit * 8
-        nbytes = n_elems * itemsize
-    n_elems = nbytes // itemsize
-    per_bit = (n_elems + 7) // 8
-    body_len = nbits * per_bit
-    body, tail = a[:body_len], a[body_len:]
-    rows = body.reshape(nbits, per_bit)
-    bits_t = np.unpackbits(rows, axis=1, bitorder="little")[:, :n_elems]  # (nbits, N)
-    bits = bits_t.T                                                       # (N, nbits)
-    elems = np.packbits(bits, axis=1, bitorder="little")                  # (N, itemsize)
-    return elems.reshape(-1).tobytes() + tail.tobytes()
+    n_elems = _bitshuffled_elems(a.size, itemsize, nbytes)
+    out = np.empty(a.size - 8 * itemsize * ((n_elems + 7) // 8)
+                   + n_elems * itemsize, np.uint8)
+    _bitunshuffle_to(a, itemsize, n_elems, out)
+    return out.tobytes()
 
 
 # ---------------------------------------------------------------------------
@@ -226,10 +273,10 @@ def unshuffle_into(buf, itemsize: int, out, nbytes=None) -> int:
 
 
 def bitunshuffle_into(buf, itemsize: int, out, nbytes=None) -> int:
-    dec = bitunshuffle(buf, itemsize, nbytes)   # packbits can't target out
     o = _as_out(out)
-    o[:len(dec)] = np.frombuffer(dec, dtype=np.uint8)
-    return len(dec)
+    a = _as_bytes(buf)
+    n_elems = _bitshuffled_elems(a.size, itemsize, nbytes)
+    return _bitunshuffle_to(a, itemsize, n_elems, o)
 
 
 def delta_decode_into(buf, itemsize: int, out, nbytes=None) -> int:
