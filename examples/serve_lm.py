@@ -18,10 +18,10 @@ import numpy as np                           # noqa: E402
 
 from repro.checkpoint import CheckpointManager      # noqa: E402
 from repro.configs import get_config, reduced       # noqa: E402
+from repro.launch.serve import restore_weights, serving_layout  # noqa: E402
 from repro.launch.train import main as train_main   # noqa: E402
 from repro.models import Model                      # noqa: E402
 from repro.serve import ServeEngine                 # noqa: E402
-from repro.train import init_train_state            # noqa: E402
 
 WORKDIR = "/tmp/repro_serve_lm"
 
@@ -35,16 +35,12 @@ def main():
         train_main(["--arch", "qwen3-8b", "--reduced", "--steps", "60",
                     "--batch", "4", "--seq-len", "64", "--ckpt-every", "60",
                     "--workdir", WORKDIR])
-    state = init_train_state(model, jax.random.key(0))
-    tmpl = {"params": state.params, "opt": state.opt, "step": state.step,
-            "err": state.err}
-    tree, meta = mgr.restore(template=tmpl)
-    print(f"restored step {int(np.asarray(tree['step']))} "
-          f"(cursor: {meta.get('data_cursor')})")
+    _, psh = serving_layout(model, tp=1)
+    params = restore_weights(mgr, {"params": psh})["params"]
+    print(f"restored the params of step {mgr.latest_step()}")
     params = jax.tree.map(
-        lambda p: p.astype(jnp.bfloat16)
-        if hasattr(p, "dtype") and p.dtype == jnp.float32 else p,
-        tree["params"])
+        lambda p: p.astype(jnp.bfloat16) if p.dtype == jnp.float32 else p,
+        params)
 
     eng = ServeEngine(model, params, batch_slots=4, max_len=96, eos_id=-1,
                       temperature=0.7, seed=1)

@@ -27,7 +27,9 @@ Fault-tolerance invariants:
     mesh's NamedSharding — restoring a 256-chip checkpoint onto 512 chips
     (or 8) is the same call with a different mesh.  ``load_pytree``
     device_puts each branch as it decodes, so the full host dict never
-    materializes alongside the device copy.
+    materializes alongside the device copy, and each device receives only
+    its own shard from the host: no whole tensor is assembled on one chip.
+    ``ckpt.h2d_bytes`` counts what the puts send, once per device.
   * **retention**: ``keep`` most recent checkpoints are kept, the rest
     garbage-collected after a successful save.
 
@@ -95,11 +97,22 @@ def _flatten_with_paths(tree) -> dict[str, Any]:
     return flat
 
 
+def _is_bf16(x) -> bool:
+    return str(getattr(x, "dtype", "")) == "bfloat16"
+
+
 def _np_view(x) -> np.ndarray:
     arr = np.asarray(jax.device_get(x))
     if arr.dtype.name == "bfloat16":        # store as raw uint16 bit pattern
         arr = arr.view(np.uint16)
     return arr
+
+
+def _h2d_bytes(arr: np.ndarray, sharding) -> int:
+    """Bytes a put of host ``arr`` under ``sharding`` sends: each device's
+    shard, counted once for every device it lands on."""
+    shards = sharding.addressable_devices_indices_map(arr.shape).values()
+    return sum(arr[idx].nbytes for idx in shards)
 
 
 def _entry_stats(stats: dict, entry: dict) -> None:
@@ -145,11 +158,15 @@ def _device_chunk_stream(x, rows_per: int, bf16: bool, stage_depth: int = 2):
             yield s, arr.shape[0], memoryview(arr).cast("B")
 
 
-def _branch_cfg(name: str, probe: np.ndarray, profile: str, tuner):
-    """Static policy or measured tuner decision for one branch probe."""
+def _branch_cfg(name: str, probe: np.ndarray, profile: str, tuner,
+                bf16: bool = False):
+    """Static policy or measured tuner decision for one branch probe.  A
+    bfloat16 tensor is stored as its uint16 bit pattern; the static policy
+    still sees it as bfloat16, and so picks a float preconditioner."""
     if tuner is not None:
         return tuner.config_for(name, probe)
-    return choose(name, probe, profile)
+    return choose(name, probe.view(jax.numpy.bfloat16) if bf16 else probe,
+                  profile)
 
 
 def _branch_stream(name: str, val, profile: str,
@@ -168,15 +185,15 @@ def _branch_stream(name: str, val, profile: str,
         arr = _np_view(val)
         return (arr.dtype.str, arr.shape,
                 split_array(arr, target_basket_bytes),
-                _branch_cfg(name, arr, profile, tuner))
-    bf16 = str(val.dtype) == "bfloat16"
+                _branch_cfg(name, arr, profile, tuner, _is_bf16(val)))
+    bf16 = _is_bf16(val)
     np_dtype = np.dtype(np.uint16) if bf16 else np.dtype(val.dtype)
     shape = tuple(val.shape)
     rows_per = basket_rows(shape, np_dtype.itemsize, target_basket_bytes)
     chunks = _device_chunk_stream(val, rows_per, bf16, stage_depth)
     first = next(chunks)
     probe = np.frombuffer(first[2], dtype=np_dtype)
-    cfg = _branch_cfg(name, probe, profile, tuner)
+    cfg = _branch_cfg(name, probe, profile, tuner, bf16)
     return (np_dtype.str, shape, itertools.chain([first], chunks), cfg)
 
 
@@ -220,8 +237,7 @@ def save_pytree(path: str, tree, profile: str = "checkpoint",
         tuner = Tuner(objective, fallback_profile=profile)
     flat = {n: v for n, v in _flatten_with_paths(tree).items() if v is not None}
     stats = {"branches": 0, "raw": 0, "comp": 0}
-    bf16_paths = [n for n, v in flat.items()
-                  if hasattr(v, "dtype") and str(v.dtype) == "bfloat16"]
+    bf16_paths = [n for n, v in flat.items() if _is_bf16(v)]
     meta = {"bf16": bf16_paths}
     if extra_meta:
         meta.update(extra_meta)
@@ -234,7 +250,7 @@ def save_pytree(path: str, tree, profile: str = "checkpoint",
         arr = _np_view(flat[name])
         return (arr.dtype.str, arr.shape,
                 split_array(arr, _TARGET_BASKET_BYTES),
-                _branch_cfg(name, arr, profile, tuner))
+                _branch_cfg(name, arr, profile, tuner, _is_bf16(flat[name])))
 
     def lend_engine(engine):
         # trial matrices fan out through the write's own engine (C-codec
@@ -315,7 +331,10 @@ def load_pytree(path: str, template=None, shardings=None, workers: int = 4,
     """Read a BasketFile back into a pytree.
 
     ``template``: pytree whose structure/leaf-Nones define the output (leaf
-    values unused).  Without it, a flat {dotted-path: array} dict returns.
+    values unused); only its branches are read, so a serving process can
+    take the params of a train checkpoint and leave the optimizer state on
+    disk.  Without it, a flat {dotted-path: array} dict of every branch
+    returns.
     ``shardings``: matching pytree of NamedShardings -> device_put per leaf
     (elastic re-shard).  ``prefetch>0`` = decompress-ahead reads.
 
@@ -345,13 +364,17 @@ def load_pytree(path: str, template=None, shardings=None, workers: int = 4,
                 return arr
             # staging symmetry: put each branch on device now, free host
             with _phase("device_put"):
-                return jax.device_put(arr, sh)
+                out = jax.device_put(arr, sh)
+            obs.counter("ckpt.h2d_bytes").inc(_h2d_bytes(arr, sh))
+            return out
 
-        flat = {n: read(n) for n in f.branch_names() if n != "__meta__"}
+        names = [n for n in f.branch_names() if n != "__meta__"]
+        if template is not None:
+            flat_t = _flatten_with_paths(template)
+            names = [n for n in names if n in flat_t]
+        flat = {n: read(n) for n in names}
     if template is None:
         return flat, meta
-
-    flat_t = _flatten_with_paths(template)
 
     def rebuild(node, prefix):
         if isinstance(node, dict):

@@ -27,7 +27,7 @@ ARCHS = {
     "llama4-scout-17b-a16e": "llama4_scout_17b",
     "seamless-m4t-medium": "seamless_m4t_medium",
     "paligemma-3b": "paligemma_3b",
-    "jamba-v0.1-52b": "jamba_v0_1_52b",
+    "jamba2-mini": "jamba2_mini",
 }
 
 

@@ -53,7 +53,8 @@ class ModelConfig:
     n_experts: int = 0
     experts_per_token: int = 1
     d_ff_expert: Optional[int] = None
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25   # None: dropless (capacity S)
+    norm_topk_prob: bool = True    # renormalise the top-k gates (HF's name)
     shared_expert: bool = False
     router_aux_weight: float = 0.01
     router_z_weight: float = 0.001

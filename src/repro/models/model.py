@@ -161,26 +161,29 @@ class Model:
             if pe.mixer in ("attn", "local"):
                 mode = "sliding" if pe.mixer == "local" else (
                     "prefix" if (cfg.n_img_tokens and not decoding) else "causal")
-                attn_out, kv = L.attention(
-                    sub["attn"], h, cfg, mode=mode, positions=positions,
-                    cache=lcache.get("self"), cache_pos=cache_pos,
-                    build_cache=build_cache,
-                    window=cfg.local_window, prefix_len=prefix_len,
-                    q_chunk=getattr(cfg, "q_chunk", 0) or 0)
+                with jax.named_scope("attention"):
+                    attn_out, kv = L.attention(
+                        sub["attn"], h, cfg, mode=mode, positions=positions,
+                        cache=lcache.get("self"), cache_pos=cache_pos,
+                        build_cache=build_cache,
+                        window=cfg.local_window, prefix_len=prefix_len,
+                        q_chunk=getattr(cfg, "q_chunk", 0) or 0)
                 if kv is not None:
                     nc["self"] = kv
                 if cfg.post_norm:
                     attn_out = L.rms_norm(sub["post_ln1"], attn_out, cfg.norm_eps)
                 x = x + attn_out
             elif pe.mixer == "mamba":
-                if decoding:
-                    mx, mstate = S.mamba_step(sub["mamba"], h, lcache["ssm_state"], cfg)
-                    nc["ssm_state"] = mstate
-                elif build_cache:
-                    mx, mstate = S.mamba(sub["mamba"], h, cfg, return_state=True)
-                    nc["ssm_state"] = mstate
-                else:
-                    mx = S.mamba(sub["mamba"], h, cfg)
+                with jax.named_scope("mamba"):
+                    if decoding:
+                        mx, mstate = S.mamba_step(sub["mamba"], h,
+                                                  lcache["ssm_state"], cfg)
+                        nc["ssm_state"] = mstate
+                    elif build_cache:
+                        mx, mstate = S.mamba(sub["mamba"], h, cfg, return_state=True)
+                        nc["ssm_state"] = mstate
+                    else:
+                        mx = S.mamba(sub["mamba"], h, cfg)
                 x = x + mx
             elif pe.mixer == "rwkv":
                 carry = lcache.get("tm_shift") if (decoding or build_cache) else None

@@ -9,10 +9,15 @@ formulation was chosen over global-sort EP-a2a (which remains a
 hillclimb variant in repro.parallel.ep_a2a).
 
 Dispatch mechanics per row:
-  1. router top-k (softmax gates renormalized over the top-k)
+  1. router top-k over the softmax; the top-k gates are renormalized to
+     sum to 1 where ``cfg.norm_topk_prob`` (llama4), and used as they are
+     otherwise (jamba)
   2. position-in-expert = exclusive cumsum of expert one-hot over S
   3. source-token index buffer (E, C) built by scatter; over-capacity
-     assignments drop (Switch semantics, capacity_factor knob)
+     assignments drop (Switch semantics, ``capacity_factor``).  With
+     ``capacity_factor=None`` the layer is dropless: C = S, since a token's
+     top-k experts are distinct and no expert can receive more than S
+     assignments of a row
   4. expert_in = gather  ->  (E, C, d) ;  batched expert einsums
   5. combine: gather back per (token, k) slot, gate-weight, sum over k
 
@@ -130,13 +135,16 @@ def moe_ffn(p: dict, x: jnp.ndarray, cfg) -> tuple[jnp.ndarray, dict]:
     B, S, d = x.shape
     E, K = cfg.n_experts, cfg.experts_per_token
     cdt = x.dtype
-    C = int(min(max(1, round(S * K / E * cfg.capacity_factor)), S * K))
+    C = S if cfg.capacity_factor is None else int(
+        min(max(1, round(S * K / E * cfg.capacity_factor)), S * K))
 
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))               # (B,S,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_k, idx_k = jax.lax.top_k(probs, K)                            # (B,S,K)
-    gate_k = gate_k / jnp.maximum(gate_k.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))           # (B,S,E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_k, idx_k = jax.lax.top_k(probs, K)                        # (B,S,K)
+        if cfg.norm_topk_prob:
+            gate_k = gate_k / jnp.maximum(gate_k.sum(-1, keepdims=True), 1e-9)
 
     # --- aux losses (Switch): load balance + z-loss
     me = probs.mean(axis=(0, 1))                                       # (E,)
@@ -174,11 +182,12 @@ def moe_ffn(p: dict, x: jnp.ndarray, cfg) -> tuple[jnp.ndarray, dict]:
 
     # --- expert FFN: batched einsums over E; f sharded = TP, E ZeRO/FSDP
     # (activations pinned to DP so the partitioner gathers *weights*)
-    g = jnp.einsum("becd,edf->becf", expert_in, p["w_gate"].astype(cdt))
-    u = jnp.einsum("becd,edf->becf", expert_in, p["w_up"].astype(cdt))
-    act = jax.nn.silu(g.astype(jnp.float32)).astype(cdt) if cfg.ffn_act == "silu" \
-        else jax.nn.gelu(g.astype(jnp.float32), approximate=True).astype(cdt)
-    expert_out = jnp.einsum("becf,efd->becd", act * u, p["w_down"].astype(cdt))
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("becd,edf->becf", expert_in, p["w_gate"].astype(cdt))
+        u = jnp.einsum("becd,edf->becf", expert_in, p["w_up"].astype(cdt))
+        act = jax.nn.silu(g.astype(jnp.float32)).astype(cdt) if cfg.ffn_act == "silu" \
+            else jax.nn.gelu(g.astype(jnp.float32), approximate=True).astype(cdt)
+        expert_out = jnp.einsum("becf,efd->becd", act * u, p["w_down"].astype(cdt))
     out_flat = constrain(expert_out.reshape(B, E * C, d), ("dp", None, None))
 
     # --- combine: per (token, k) read its slot back, gate-weight, sum over k

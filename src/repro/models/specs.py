@@ -26,7 +26,7 @@ __all__ = ["ParamSpec", "init_params", "abstract_params", "map_logical", "tree_p
 class ParamSpec:
     shape: tuple
     axes: tuple                 # logical axis name (or None) per dim
-    init: str = "normal"        # normal | zeros | ones | scaled
+    init: str = "normal"        # normal | zeros | ones | s4d_real | dt_bias
     scale: float = 1.0          # stddev multiplier (normal) / fan-in override
     dtype: Any = jnp.float32
 
@@ -60,12 +60,26 @@ def init_params(spec_tree, key, param_dtype=None):
             arr = jnp.zeros(spec.shape, dtype)
         elif spec.init == "ones":
             arr = jnp.full(spec.shape, spec.scale, dtype)  # "ones" = constant ``scale``
+        elif spec.init == "s4d_real":     # Mamba's A_log: log(1..n) in every row
+            n = spec.shape[-1]
+            arr = jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
+                                   spec.shape).astype(dtype)
+        elif spec.init == "dt_bias":      # Mamba's: softplus^-1(dt), dt log-uniform
+            arr = _dt_bias(k, spec.shape).astype(dtype)
         else:
             fan_in = spec.shape[0] if len(spec.shape) >= 2 else max(spec.shape[-1], 1)
             std = spec.scale / np.sqrt(max(fan_in, 1))
             arr = (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(dtype)
         out_flat[path] = arr
     return _unflatten(out_flat)
+
+
+def _dt_bias(key, shape):
+    """Mamba's dt bias: the inverse softplus of dt drawn log-uniformly from
+    [1e-3, 1e-1], so that softplus(bias) starts at such a dt."""
+    u = jax.random.uniform(key, shape, jnp.float32)
+    dt = jnp.exp(u * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def abstract_params(spec_tree, param_dtype=None):

@@ -12,6 +12,12 @@ kernel's SRAM blocking — and the outer scan is the remat boundary.
 Recurrence (Mamba-1, per channel c and state n):
     h_t = exp(dt_t[c] * A[c, n]) * h_{t-1} + dt_t[c] * B_t[n] * x_t[c]
     y_t[c] = sum_n C_t[n] * h_t[c, n] + D[c] * x_t[c]
+
+As in Jamba, the low-rank dt input, B and C each pass an RMSNorm of their
+own (``dt_norm``, ``b_norm``, ``c_norm``) right after ``x_proj``.  A_log,
+D and dt_bias start as Mamba starts them: A = 1..d_state in every channel,
+D = 1, and dt_bias the inverse softplus of a dt drawn log-uniformly from
+[1e-3, 1e-1].
 """
 
 from __future__ import annotations
@@ -19,6 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .layers import norm_specs, rms_norm
 from .specs import ParamSpec
 from repro.parallel.actctx import constrain
 
@@ -36,10 +43,13 @@ def mamba_specs(cfg) -> dict:
         "conv_b": ParamSpec((di,), ("inner",), init="zeros"),
         "x_proj": ParamSpec((di, dt_rank + 2 * n), ("inner", None)),
         "dt_proj": ParamSpec((dt_rank, di), (None, "inner")),
-        "dt_bias": ParamSpec((di,), ("inner",), init="ones", scale=0.01),
-        "a_log": ParamSpec((di, n), ("inner", None), init="ones"),
+        "dt_bias": ParamSpec((di,), ("inner",), init="dt_bias"),
+        "a_log": ParamSpec((di, n), ("inner", None), init="s4d_real"),
         "d_skip": ParamSpec((di,), ("inner",), init="ones"),
         "out_proj": ParamSpec((di, d), ("inner", "embed")),
+        "dt_norm": norm_specs(dt_rank),
+        "b_norm": norm_specs(n),
+        "c_norm": norm_specs(n),
     }
 
 
@@ -49,6 +59,9 @@ def _ssm_params(p, x, cfg):
     dt_rank = p["x_proj"].shape[1] - 2 * n
     xp = jnp.einsum("bsc,cr->bsr", x, p["x_proj"].astype(x.dtype))
     dt_in, b_in, c_in = jnp.split(xp, [dt_rank, dt_rank + n], axis=-1)
+    dt_in = rms_norm(p["dt_norm"], dt_in, cfg.norm_eps)
+    b_in = rms_norm(p["b_norm"], b_in, cfg.norm_eps)
+    c_in = rms_norm(p["c_norm"], c_in, cfg.norm_eps)
     dt = jax.nn.softplus(
         jnp.einsum("bsr,rc->bsc", dt_in, p["dt_proj"].astype(x.dtype)).astype(jnp.float32)
         + p["dt_bias"].astype(jnp.float32))                              # (B,S,di)

@@ -84,7 +84,7 @@ def test_encdec_decode():
     assert np.isfinite(np.asarray(lg)).all()
 
 
-@pytest.mark.parametrize("arch", ["rwkv6-1.6b", "jamba-v0.1-52b"])
+@pytest.mark.parametrize("arch", ["rwkv6-1.6b", "jamba2-mini"])
 def test_recurrent_stepwise_matches_full(arch):
     """Chunked full-sequence pass == step-by-step decode (state parity).
 
@@ -128,7 +128,7 @@ def test_mamba_module_stepwise_strict():
     import dataclasses
     from repro.models import ssm as S
     from repro.models.specs import init_params
-    cfg = reduced(get_config("jamba-v0.1-52b"))
+    cfg = reduced(get_config("jamba2-mini"))
     p = init_params(S.mamba_specs(cfg), jax.random.key(9))
     x = (jax.random.normal(jax.random.key(5), (2, 12, cfg.d_model)) * 0.5
          ).astype(jnp.bfloat16)
@@ -151,7 +151,7 @@ def test_shapes_assignment():
     for arch in ARCHS:
         cfg = get_config(arch)
         names = {s.name for s in shapes_for(cfg)}
-        if arch in ("rwkv6-1.6b", "jamba-v0.1-52b"):
+        if arch in ("rwkv6-1.6b", "jamba2-mini"):
             assert "long_500k" in names
         else:
             assert "long_500k" not in names

@@ -51,7 +51,7 @@ def test_experts_fsdp(mesh):
 
 def test_each_mesh_axis_used_once(mesh):
     pc = ParallelismConfig(zero3=True)
-    for arch in ("qwen3-8b", "llama4-maverick-400b-a17b", "jamba-v0.1-52b"):
+    for arch in ("qwen3-8b", "llama4-maverick-400b-a17b", "jamba2-mini"):
         model = Model(get_config(arch))
         from repro.models.specs import tree_paths
         for path, spec in tree_paths(model.param_specs()).items():
