@@ -20,8 +20,11 @@ delta swaps, so no step expands the data to one byte per bit.  The stored
 format is the one the former ``unpackbits``/``packbits`` implementation
 wrote, byte for byte: bit plane ``8b + j`` (byte ``b``, bit ``j``, LSB
 first) of all N elements, packed LSB-first, ``ceil(N/8)`` bytes a plane,
-the last byte zero-padded.  Every step is a numpy ufunc or array copy, which
-release the interpreter lock, so decode threads run the inverse in parallel.
+the last byte zero-padded.  Every byte copy, in both directions, runs with
+the long axis (elements, or words of a plane) innermost: a few long copies,
+one a bit or byte plane, not one copy whose inner loop is 8 or ``itemsize``
+bytes.  Every step is a numpy ufunc or array copy, which release the
+interpreter lock, so decode threads run the inverse in parallel.
 
 The device path (Pallas TPU kernels) lives in ``repro.kernels``; this module
 is the reference implementation those kernels are tested against.
@@ -167,12 +170,18 @@ def _bitunshuffle_to(a: np.ndarray, itemsize: int, n_elems: int,
     """Write the inverse of :func:`bitshuffle` of ``a`` into ``o``."""
     per_bit = (n_elems + 7) // 8
     body = 8 * itemsize * per_bit
+    # byte j of word c is byte c of bit plane j: one copy a bit plane
+    bits = a[:body].reshape(itemsize, 8, per_bit)
     planes = np.empty((itemsize, per_bit, 8), np.uint8)
-    planes[...] = a[:body].reshape(itemsize, 8, per_bit).transpose(0, 2, 1)
+    for j in range(8):
+        planes[:, :, j] = bits[:, j, :]
     planes = planes.reshape(itemsize, 8 * per_bit)
     _transpose_bits8x8(planes.view("<u8"))
+    # byte plane b is byte b of every element: one copy a byte plane
     n = n_elems * itemsize
-    o[:n].reshape(n_elems, itemsize)[...] = planes[:, :n_elems].T
+    elems = o[:n].reshape(n_elems, itemsize)
+    for b in range(itemsize):
+        elems[:, b] = planes[b, :n_elems]
     tail = a.size - body
     o[n:n + tail] = a[body:]
     return n + tail

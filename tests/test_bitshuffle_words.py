@@ -55,20 +55,30 @@ def test_bitshuffle_accepts_buffers():
     assert bitshuffle(memoryview(x.tobytes()), 4) == want
 
 
-@pytest.mark.parametrize("n_elems,tail", [(9, 3), (1000, 0), (262144, 1)])
+# (itemsize, n_elems, tail): counts off a multiple of 8 with a tail at every
+# itemsize the checkpoints store (bf16, f32, f64), and single-word planes
+# (n_elems <= 8, so ceil(N/8) == 1)
+INTO_LAYOUTS = [(4, 9, 3), (4, 1000, 0), (4, 262144, 1),
+                (2, 9, 1), (2, 1003, 1), (2, 262147, 1),
+                (8, 9, 7), (8, 1003, 5), (8, 65541, 3),
+                (2, 5, 1), (4, 8, 0), (8, 1, 7)]
+
+
+@pytest.mark.parametrize("itemsize,n_elems,tail", INTO_LAYOUTS)
 @pytest.mark.parametrize("dest", ["ndarray", "memoryview"])
 @pytest.mark.parametrize("via_spec", [False, True])
-def test_bitunshuffle_into_fills_offset_slice_exactly(n_elems, tail, dest, via_spec):
-    rng = np.random.default_rng(n_elems + tail)
-    x = rng.integers(0, 256, 4 * n_elems + tail, dtype=np.uint8).tobytes()
-    y = bitshuffle_oracle(x, 4)
+def test_bitunshuffle_into_fills_offset_slice_exactly(itemsize, n_elems, tail,
+                                                      dest, via_spec):
+    rng = np.random.default_rng(100 * itemsize + n_elems + tail)
+    x = rng.integers(0, 256, itemsize * n_elems + tail, dtype=np.uint8).tobytes()
+    y = bitshuffle_oracle(x, itemsize)
     offset, spare = 13, 29
     big = np.full(offset + len(x) + spare, 0xA5, np.uint8)
     out = big[offset:] if dest == "ndarray" else memoryview(big)[offset:]
     if via_spec:
-        written = undo_precond_into("bitshuffle4", y, out, len(x))
+        written = undo_precond_into(f"bitshuffle{itemsize}", y, out, len(x))
     else:
-        written = bitunshuffle_into(y, 4, out, 4 * n_elems)
+        written = bitunshuffle_into(y, itemsize, out, itemsize * n_elems)
     assert written == len(x)
     assert big[offset:offset + len(x)].tobytes() == x
     assert (big[:offset] == 0xA5).all() and (big[offset + len(x):] == 0xA5).all()
@@ -90,15 +100,16 @@ def test_bitunshuffle_into_refuses_readonly_and_strided_out():
         undo_precond_into("bitshuffle4", y, strided, 400)
 
 
-def test_concurrent_bitunshuffle_into_one_array():
+@pytest.mark.parametrize("itemsize,dtype", [(2, np.float16), (4, np.float32)])
+def test_concurrent_bitunshuffle_into_one_array(itemsize, dtype):
     """Four threads decode different baskets into disjoint slices of one
     array at once; every slice comes back bit for bit."""
-    n_threads, rounds, size = 4, 8, 4 * 65536 + 3
+    n_threads, rounds, size = 4, 8, itemsize * 65536 + itemsize - 1
     rng = np.random.default_rng(11)
-    blocks = [rng.standard_normal(size // 4).astype(np.float32).tobytes()
-              + bytes(rng.integers(0, 256, size % 4, dtype=np.uint8))
+    blocks = [rng.standard_normal(size // itemsize).astype(dtype).tobytes()
+              + bytes(rng.integers(0, 256, size % itemsize, dtype=np.uint8))
               for _ in range(n_threads)]
-    shuffled = [bitshuffle_oracle(b, 4) for b in blocks]
+    shuffled = [bitshuffle_oracle(b, itemsize) for b in blocks]
     dest = np.zeros(n_threads * size, np.uint8)
     start = threading.Barrier(n_threads)
     errors = []
@@ -107,7 +118,7 @@ def test_concurrent_bitunshuffle_into_one_array():
         try:
             start.wait(timeout=30)
             for _ in range(rounds):
-                undo_precond_into("bitshuffle4", shuffled[i],
+                undo_precond_into(f"bitshuffle{itemsize}", shuffled[i],
                                   dest[i * size:(i + 1) * size], size)
         except Exception as e:      # surfaced by the assertion below
             errors.append(e)
